@@ -54,13 +54,22 @@ def device_memory_budget() -> int:
 
     Precedence: ``REPRO_DEVICE_MEM_BUDGET`` env var > the default device's
     reported memory (``memory_stats()["bytes_limit"]``, TPU/GPU) > half of
-    host MemAvailable (CPU devices share host RAM) > 4 GiB.
+    host MemAvailable (CPU devices share host RAM) > 4 GiB.  A TPU must
+    report its limit: there this raises instead of budgeting against the
+    host's RAM.
     """
     env = os.environ.get(_ENV_BUDGET)
     if env:
         return int(float(env))
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            raise RuntimeError(
+                f"{dev.device_kind} reports no memory_stats()['bytes_limit']")
+        return int(limit)
     try:
-        stats = jax.devices()[0].memory_stats()
+        stats = dev.memory_stats()
         if stats and stats.get("bytes_limit"):
             return int(stats["bytes_limit"])
     except Exception:  # memory_stats unimplemented on some backends

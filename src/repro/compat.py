@@ -1,20 +1,17 @@
-"""Small cross-version JAX compatibility helpers.
-
-The repo targets recent JAX, but two APIs moved under our feet:
-
-* ``jax.sharding.AxisType`` (explicit/auto axis types) does not exist on
-  older releases — :func:`make_auto_mesh` passes ``axis_types`` only when
-  available (every mesh here is fully ``Auto``, which is also the default
-  on versions without the enum).
-* ``jax.lax.axis_size`` is similarly recent; see
-  ``repro.core.distributed._axis_size`` for the in-shard_map fallback.
-"""
+"""Runtime setup shared by the launchers and the distributed drivers:
+mesh construction and the persistent compilation cache."""
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Sequence
 
 import jax
+
+# <repo root>/.jax_cache (git-ignored).  A fixed path: a later process
+# finds the programs an earlier one compiled only under the same path.
+_DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def make_auto_mesh(
@@ -22,12 +19,24 @@ def make_auto_mesh(
     axis_names: Sequence[str],
     devices=None,
 ):
-    """``jax.make_mesh`` with all axes ``Auto``, on any supported version."""
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (
-            jax.sharding.AxisType.Auto,
-        ) * len(tuple(axis_names))
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    kwargs = {"axis_types": (jax.sharding.AxisType.Auto,) * len(
+        tuple(axis_names))}
     if devices is not None:
         kwargs["devices"] = devices
     return jax.make_mesh(tuple(shape), tuple(axis_names), **kwargs)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    Call from a launcher's ``main()``, never at import.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
+    is changed; otherwise the cache goes to ``<repo root>/.jax_cache``.
+    Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(_DEFAULT_CACHE_DIR))
+    return str(_DEFAULT_CACHE_DIR)

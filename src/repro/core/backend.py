@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.block_sweep.ops import block_sweep as _pallas_block
+from repro.kernels.common import matmul as _mm
 from repro.kernels.block_sweep.ref import block_sweep_ref as _xla_block
 from repro.kernels.greedy_update.ops import greedy_update as _pallas_pivot
 from repro.kernels.greedy_update.ref import greedy_update_ref as _xla_pivot
@@ -126,8 +127,8 @@ def _plane_split_pivot(q, S, acc, norms_sq):
     """
     qr, qi = q.real, q.imag
     Sr, Si = S.real, S.imag
-    cr = qr @ Sr + qi @ Si   # Re(q^H S)
-    ci = qr @ Si - qi @ Sr   # Im(q^H S)
+    cr = _mm(qr, Sr) + _mm(qi, Si)   # Re(q^H S)
+    ci = _mm(qr, Si) - _mm(qi, Sr)   # Im(q^H S)
     c = jax.lax.complex(cr, ci).astype(S.dtype)
     acc_out = acc + (cr * cr + ci * ci).astype(acc.dtype)
     res = norms_sq - acc_out
@@ -140,11 +141,11 @@ def _plane_split_project(v, Q):
     Qr, Qi = Q.real, Q.imag
     vr, vi = v.real, v.imag
     # c = Q^H v = (Qr - i Qi)^T (vr + i vi)
-    cr = vr @ Qr + vi @ Qi
-    ci = vi @ Qr - vr @ Qi
+    cr = _mm(vr, Qr) + _mm(vi, Qi)
+    ci = _mm(vi, Qr) - _mm(vr, Qi)
     # v' = v - Q c
-    vr_out = vr - (Qr @ cr - Qi @ ci)
-    vi_out = vi - (Qr @ ci + Qi @ cr)
+    vr_out = vr - (_mm(Qr, cr) - _mm(Qi, ci))
+    vi_out = vi - (_mm(Qr, ci) + _mm(Qi, cr))
     return (
         jax.lax.complex(vr_out, vi_out).astype(v.dtype),
         jax.lax.complex(cr, ci).astype(Q.dtype),
@@ -200,11 +201,11 @@ def _plane_split_panel_project(V, Q):
     Qr, Qi = Q.real, Q.imag
     Vr, Vi = V.real, V.imag
     # C = Q^H V = (Qr - i Qi)^T (Vr + i Vi)
-    Cr = Qr.T @ Vr + Qi.T @ Vi
-    Ci = Qr.T @ Vi - Qi.T @ Vr
+    Cr = _mm(Qr.T, Vr) + _mm(Qi.T, Vi)
+    Ci = _mm(Qr.T, Vi) - _mm(Qi.T, Vr)
     # V' = V - Q C
-    Vr_out = Vr - (Qr @ Cr - Qi @ Ci)
-    Vi_out = Vi - (Qr @ Ci + Qi @ Cr)
+    Vr_out = Vr - (_mm(Qr, Cr) - _mm(Qi, Ci))
+    Vi_out = Vi - (_mm(Qr, Ci) + _mm(Qi, Cr))
     return (
         jax.lax.complex(Vr_out, Vi_out).astype(V.dtype),
         jax.lax.complex(Cr, Ci).astype(Q.dtype),
@@ -246,8 +247,8 @@ def _plane_split_block_sweep(Qnew, S, acc):
     Qr, Qi = Qnew.real, Qnew.imag
     Sr, Si = S.real, S.imag
     # C = Qnew^H S = (Qr - i Qi)^T (Sr + i Si)
-    Cr = Qr.T @ Sr + Qi.T @ Si
-    Ci = Qr.T @ Si - Qi.T @ Sr
+    Cr = _mm(Qr.T, Sr) + _mm(Qi.T, Si)
+    Ci = _mm(Qr.T, Si) - _mm(Qi.T, Sr)
     C = jax.lax.complex(Cr, Ci).astype(S.dtype)
     acc_out = acc + jnp.sum(Cr * Cr + Ci * Ci, axis=0).astype(acc.dtype)
     return C, acc_out
@@ -286,8 +287,8 @@ def _plane_split_sketch_fold(T, Omega, Y):
     float summation order."""
     Tr, Ti = T.real, T.imag
     Or, Oi = Omega.real, Omega.imag
-    Yr = Y.real + (Tr @ Or - Ti @ Oi)
-    Yi = Y.imag + (Tr @ Oi + Ti @ Or)
+    Yr = Y.real + (_mm(Tr, Or) - _mm(Ti, Oi))
+    Yi = Y.imag + (_mm(Tr, Oi) + _mm(Ti, Or))
     return jax.lax.complex(Yr, Yi).astype(Y.dtype)
 
 
@@ -310,7 +311,7 @@ def sketch_fold(
     resolved = resolve_backend(backend)
     if resolved != "xla_ref" and jnp.iscomplexobj(T):
         return _plane_split_sketch_fold(T, Omega, Y)
-    return Y + T @ Omega
+    return Y + _mm(T, Omega)
 
 
 # ------------------------------------------------ batched (B-lane) forms ----
@@ -391,14 +392,14 @@ def _fused_shared_pivot(q, S, acc, norms_sq):
     if jnp.iscomplexobj(S):
         L = jnp.concatenate([q.real, q.imag], axis=0)     # (2B, N)
         Sr, Si = S.real, S.imag
-        A = L @ Sr                                        # (2B, M)
-        Bm = L @ Si
+        A = _mm(L, Sr)                                    # (2B, M)
+        Bm = _mm(L, Si)
         cr = A[:B] + Bm[B:]
         ci = Bm[:B] - A[B:]
         c = jax.lax.complex(cr, ci).astype(S.dtype)
         acc_out = acc + (cr * cr + ci * ci).astype(acc.dtype)
     else:
-        c = q @ S                                         # (B, M) one GEMM
+        c = _mm(q, S)                                     # (B, M) one GEMM
         acc_out = acc + (c * c).astype(acc.dtype)
     res = norms_sq - acc_out
     return (c, acc_out, jnp.max(res, axis=1),
@@ -499,14 +500,14 @@ def _fused_shared_block_sweep(Qnew, S, acc):
     if jnp.iscomplexobj(S):
         L = jnp.concatenate([Qh.real, Qh.imag], axis=0)   # (2Bp, N)
         Sr, Si = S.real, S.imag
-        A = L @ Sr
-        Bm = L @ Si
+        A = _mm(L, Sr)
+        Bm = _mm(L, Si)
         Cr = A[:B * p] + Bm[B * p:]
         Ci = Bm[:B * p] - A[B * p:]
         C = jax.lax.complex(Cr, Ci).astype(S.dtype).reshape(B, p, -1)
         sq = (Cr * Cr + Ci * Ci).reshape(B, p, -1)
     else:
-        C = (Qh @ S).reshape(B, p, -1)
+        C = _mm(Qh, S).reshape(B, p, -1)
         sq = C * C
     acc_out = acc + jnp.sum(sq, axis=1).astype(acc.dtype)
     return C, acc_out
@@ -573,9 +574,9 @@ def batched_sketch_fold(
     if resolved != "xla_ref" or t_ax == 0:
         inner = (_plane_split_sketch_fold
                  if resolved != "xla_ref" and jnp.iscomplexobj(T)
-                 else (lambda t, o, y: y + t @ o))
+                 else (lambda t, o, y: y + _mm(t, o)))
         return jax.vmap(inner, in_axes=(t_ax, o_ax, 0))(T, Omega, Y)
-    outs = [Y[b] + T @ (Omega if o_ax is None else Omega[b])
+    outs = [Y[b] + _mm(T, Omega if o_ax is None else Omega[b])
             for b in range(B)]
     return jnp.stack(outs)
 
@@ -586,8 +587,8 @@ def _plane_split_sketch_project(T, Y):
     Tr, Ti = T.real, T.imag
     Yr, Yi = Y.real, Y.imag
     # Z = T^H Y = (Tr - i Ti)^T (Yr + i Yi)
-    Zr = Tr.T @ Yr + Ti.T @ Yi
-    Zi = Tr.T @ Yi - Ti.T @ Yr
+    Zr = _mm(Tr.T, Yr) + _mm(Ti.T, Yi)
+    Zi = _mm(Tr.T, Yi) - _mm(Ti.T, Yr)
     return jax.lax.complex(Zr, Zi).astype(T.dtype)
 
 
@@ -606,4 +607,4 @@ def sketch_project(
     resolved = resolve_backend(backend)
     if resolved != "xla_ref" and jnp.iscomplexobj(T):
         return _plane_split_sketch_project(T, Y)
-    return T.conj().T @ Y
+    return _mm(T.conj().T, Y)

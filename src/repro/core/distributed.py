@@ -42,9 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map
-
 from repro.core import backend as _backend
+from repro.kernels.common import matmul as _mm
 from repro.core.greedy import (
     GreedyResult,
     STOP_FLOOR,
@@ -181,26 +180,18 @@ def _save_dist_checkpoint(directory: str, seq: int, state, ref_sq, scale,
     return seq
 
 
-def _axis_size(a: str):
-    """Size of a mapped axis; ``psum(1, a)`` constant-folds to it and works
-    on jax versions without ``jax.lax.axis_size``."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)
-
-
 def _axis_index(axes: Sequence[str]):
     """Flattened device rank over (possibly several) mesh axes."""
     idx = jnp.zeros((), jnp.int32)
     for a in axes:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
 def _axis_count(axes: Sequence[str]):
     n = 1
     for a in axes:
-        n *= _axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
@@ -275,12 +266,12 @@ def _make_dist_greedy_step(mesh, kappa, max_passes, backend):
     specs = state_specs(mesh)
     s_spec = P(None, axes)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         _make_local_step(axes, kappa, max_passes, backend),
         mesh=mesh,
         in_specs=(s_spec, specs),
         out_specs=specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(1,))
 
@@ -342,12 +333,12 @@ def _make_dist_greedy_chunk(mesh, chunk, kappa, max_passes, backend,
         )
         return state, n_done, stop
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_chunk,
         mesh=mesh,
         in_specs=(s_spec, specs, P(), P(), P(), P()),
         out_specs=(specs, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     # donate=False supports repeated application to one state (benchmarks)
     return jax.jit(sharded, donate_argnums=(1,) if donate else ())
@@ -513,13 +504,13 @@ def _make_dist_block_greedy_chunk(mesh, chunk, p, kappa, max_passes,
     specs = state_specs(mesh)
     s_spec = P(None, axes)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         _make_local_block_chunk(axes, chunk, p, kappa, max_passes, backend,
                                 check_refresh, panel),
         mesh=mesh,
         in_specs=(s_spec, specs, P(), P(), P(), P()),
         out_specs=(specs, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     # donate=False supports repeated application to one state (benchmarks)
     return jax.jit(sharded, donate_argnums=(1,) if donate else ())
@@ -533,14 +524,14 @@ def make_dist_refresh(mesh: Mesh):
     s_spec = P(None, axes)
 
     def local_refresh(S_loc, state):
-        C = state.Q.conj().T @ S_loc
-        E = S_loc - state.Q @ C
+        C = _mm(state.Q.conj().T, S_loc)
+        E = S_loc - _mm(state.Q, C)
         res = jnp.sum(jnp.abs(E) ** 2, axis=0).astype(state.norms_sq.dtype)
         return state._replace(norms_sq=res, acc=jnp.zeros_like(state.acc))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_refresh, mesh=mesh, in_specs=(s_spec, specs),
-        out_specs=specs, check_rep=False,
+        out_specs=specs, check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(1,))
 

@@ -21,6 +21,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import matmul as _mm
+
 
 class EIMResult(NamedTuple):
     nodes: jax.Array   # (k,) int32 interpolation rows ("empirical nodes")
@@ -52,7 +54,7 @@ def eim_nodes(Q: jax.Array) -> EIMResult:
         )
         rhs = jnp.where(row_mask, qi[nodes], jnp.zeros((k,), Q.dtype))
         c = jnp.linalg.solve(A, rhs)
-        r = qi - Q @ jnp.where(row_mask, c, jnp.zeros_like(c))
+        r = qi - _mm(Q, jnp.where(row_mask, c, jnp.zeros_like(c)))
         node_i = jnp.argmax(jnp.abs(r)).astype(jnp.int32)
         return (nodes.at[i].set(node_i),)
 
@@ -60,15 +62,15 @@ def eim_nodes(Q: jax.Array) -> EIMResult:
     nodes0 = nodes0.at[0].set(jnp.argmax(jnp.abs(Q[:, 0])).astype(jnp.int32))
     (nodes,) = jax.lax.fori_loop(1, k, body, (nodes0,))
 
-    B = Q @ jnp.linalg.inv(Q[nodes, :])
+    B = _mm(Q, jnp.linalg.inv(Q[nodes, :]))
     return EIMResult(nodes=nodes, B=B)
 
 
 def empirical_interpolant(B: jax.Array, nodes: jax.Array, f: jax.Array):
     """Evaluate the empirical interpolant of f (vector or batch of columns)."""
     if f.ndim == 1:
-        return B @ f[nodes]
-    return B @ f[nodes, :]
+        return _mm(B, f[nodes])
+    return _mm(B, f[nodes, :])
 
 
 def roq_weights(data: jax.Array, quad_w: jax.Array, B: jax.Array):
@@ -77,4 +79,4 @@ def roq_weights(data: jax.Array, quad_w: jax.Array, B: jax.Array):
     <d, h> = sum_x w_x conj(d_x) h_x ~= sum_j omega_j h(node_j) with
     omega = B^T (w * conj(d)).
     """
-    return B.T @ (quad_w.astype(B.dtype) * jnp.conj(data))
+    return _mm(B.T, quad_w.astype(B.dtype) * jnp.conj(data))

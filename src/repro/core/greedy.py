@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import backend as _backend
+from repro.kernels.common import matmul as _mm
 
 
 class GreedyResult(NamedTuple):
@@ -376,8 +377,8 @@ def greedy_refresh(S: jax.Array, state: GreedyState) -> GreedyState:
     times), storing the exact residual^2 as the new reference so subsequent
     Eq.-(6.3) updates are accurate relative to the *refreshed* scale.
     """
-    C = state.Q.conj().T @ S             # (max_k, M); zero rows are no-ops
-    E = S - state.Q @ C
+    C = _mm(state.Q.conj().T, S)        # (max_k, M); zero rows are no-ops
+    E = S - _mm(state.Q, C)
     res = jnp.sum(jnp.abs(E) ** 2, axis=0).astype(state.norms_sq.dtype)
     return state._replace(norms_sq=res, acc=jnp.zeros_like(state.acc))
 

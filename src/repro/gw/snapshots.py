@@ -13,8 +13,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.gw.waveform import taylorf2
+from repro.gw.waveform import taylorf2_batch
 
 
 def build_snapshot_matrix(
@@ -22,28 +23,24 @@ def build_snapshot_matrix(
     m1s: np.ndarray,
     m2s: np.ndarray,
     dtype=jnp.complex64,
-    sharding: jax.sharding.NamedSharding | None = None,
-    chunk: int = 4096,
+    sharding: NamedSharding | None = None,
 ) -> jax.Array:
-    """Build S (N, M) column-chunked; optionally placed with ``sharding``.
+    """Build S (N, M) on the device; optionally column-sharded.
 
-    ``sharding`` should shard the column (second) axis; each chunk is
-    generated jit-compiled and placed directly, so the full matrix never
-    exists unsharded (the paper's "may be too large to load into memory"
-    setting).
+    ``sharding`` must shard the column (second) axis.  The parameter
+    vectors are then placed with the matching 1-D sharding and the model
+    is jitted with ``out_shardings=sharding``, so each device evaluates
+    the waveforms of its own column range: the full matrix never exists
+    on one device or on the host (the paper's "may be too large to load
+    into memory" setting).
     """
     f = jnp.asarray(f)
-    gen = jax.jit(jax.vmap(lambda a, b: taylorf2(f, a, b, dtype=dtype)))
-    M = len(m1s)
-    outs = []
-    # generate on host CPU (jit's backend= kwarg is deprecated; the
-    # default_device context is the supported spelling), then place
-    with jax.default_device(jax.local_devices(backend="cpu")[0]):
-        for lo in range(0, M, chunk):
-            hi = min(lo + chunk, M)
-            block = gen(jnp.asarray(m1s[lo:hi]), jnp.asarray(m2s[lo:hi])).T
-            outs.append(block)
-        S = jnp.concatenate(outs, axis=1)
-    if sharding is not None:
-        S = jax.device_put(S, sharding)
-    return S
+
+    def gen(m1, m2):
+        return taylorf2_batch(f, m1, m2, dtype=dtype)
+
+    if sharding is None:
+        return jax.jit(gen)(jnp.asarray(m1s), jnp.asarray(m2s))
+    params = NamedSharding(sharding.mesh, P(sharding.spec[1]))
+    m1, m2 = (jax.device_put(np.asarray(m), params) for m in (m1s, m2s))
+    return jax.jit(gen, out_shardings=sharding)(m1, m2)

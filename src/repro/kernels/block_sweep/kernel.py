@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import PRECISION
+
 
 def _kernel_real(qh_ref, s_ref, acc_ref, c_ref, acc_out_ref, c_scr):
     n_i = pl.program_id(1)
@@ -41,7 +43,8 @@ def _kernel_real(qh_ref, s_ref, acc_ref, c_ref, acc_out_ref, c_scr):
         c_scr[...] = jnp.zeros_like(c_scr)
 
     c_scr[...] += jnp.dot(
-        qh_ref[...], s_ref[...], preferred_element_type=jnp.float32
+        qh_ref[...], s_ref[...], precision=PRECISION,
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(n_i == n_blocks - 1)
@@ -67,10 +70,14 @@ def _kernel_complex(qhr_ref, qhi_ref, sr_ref, si_ref, acc_ref,
     sr = sr_ref[...]
     si = si_ref[...]
     # C = Qnew^H S = (Qr - i Qi)^T (Sr + i Si); qh* hold Q*^T
-    cr_scr[...] += jnp.dot(qhr, sr, preferred_element_type=jnp.float32)
-    cr_scr[...] += jnp.dot(qhi, si, preferred_element_type=jnp.float32)
-    ci_scr[...] += jnp.dot(qhr, si, preferred_element_type=jnp.float32)
-    ci_scr[...] -= jnp.dot(qhi, sr, preferred_element_type=jnp.float32)
+    cr_scr[...] += jnp.dot(qhr, sr, precision=PRECISION,
+                           preferred_element_type=jnp.float32)
+    cr_scr[...] += jnp.dot(qhi, si, precision=PRECISION,
+                           preferred_element_type=jnp.float32)
+    ci_scr[...] += jnp.dot(qhr, si, precision=PRECISION,
+                           preferred_element_type=jnp.float32)
+    ci_scr[...] -= jnp.dot(qhi, sr, precision=PRECISION,
+                           preferred_element_type=jnp.float32)
 
     @pl.when(n_i == n_blocks - 1)
     def _():
@@ -83,7 +90,7 @@ def _kernel_complex(qhr_ref, qhi_ref, sr_ref, si_ref, acc_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("nt", "mt", "interpret"))
-def block_sweep_real(qh, S, acc, nt: int = 512, mt: int = 1024,
+def block_sweep_real(qh, S, acc, nt: int = 256, mt: int = 1024,
                      interpret: bool = True):
     """Real-dtype fused panel sweep on padded inputs (see ops.py).
 
@@ -116,7 +123,7 @@ def block_sweep_real(qh, S, acc, nt: int = 512, mt: int = 1024,
 
 
 @functools.partial(jax.jit, static_argnames=("nt", "mt", "interpret"))
-def block_sweep_complex(qhr, qhi, Sr, Si, acc, nt: int = 512,
+def block_sweep_complex(qhr, qhi, Sr, Si, acc, nt: int = 256,
                         mt: int = 1024, interpret: bool = True):
     """Complex fused panel sweep on split re/im planes (padded; see ops.py)."""
     p, _ = qhr.shape

@@ -34,7 +34,7 @@ def block_sweep(
     Qnew: jax.Array,
     S: jax.Array,
     acc: jax.Array,
-    nt: int = 512,
+    nt: int = 256,
     mt: int = 1024,
     interpret: bool | None = None,
 ):
@@ -90,7 +90,7 @@ def batched_block_sweep(
     Qnew: jax.Array,
     S: jax.Array,
     acc: jax.Array,
-    nt: int = 512,
+    nt: int = 256,
     mt: int = 1024,
     interpret: bool | None = None,
 ):

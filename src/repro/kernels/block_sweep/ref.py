@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import matmul as _mm
+
 
 def block_sweep_ref(Qnew: jax.Array, S: jax.Array, acc: jax.Array):
     """Reference semantics of one blocked pivot-sweep update.
@@ -23,6 +25,6 @@ def block_sweep_ref(Qnew: jax.Array, S: jax.Array, acc: jax.Array):
       C:       (p, M) = Qnew^H S (dtype of S) — the block's rows of R.
       acc_out: (M,) = acc + sum_i |C[i]|^2.
     """
-    C = Qnew.conj().T @ S
+    C = _mm(Qnew.conj().T, S)
     acc_out = acc + jnp.sum(jnp.abs(C) ** 2, axis=0).astype(acc.dtype)
     return C, acc_out

@@ -21,9 +21,11 @@ same pass.
 Tiling: S is blocked (Nt x Mt) in VMEM with the column dimension M as the
 outer (parallel) grid axis and the row dimension N as the inner (reduction)
 axis, accumulating partial dot products into a VMEM scratch of width Mt.
-Default (Nt, Mt) = (512, 1024): f32 planes use 2 * 2 MB VMEM for S-blocks
-(re+im), well inside the ~16 MB v5e VMEM budget, and Mt = 1024 = 8 * 128
-lanes keeps the MXU/VPU fully shaped.
+Default (Nt, Mt) = (256, 1024): f32 S-blocks of 1 MB per plane, and
+Mt = 1024 = 8 * 128 lanes keeps the MXU/VPU fully shaped.  The dots run at
+full f32 (``common.PRECISION``), for which Mosaic splits each block into
+bf16 parts in VMEM: at Nt = 512 the complex kernel needs ~25 MB, over the
+16 MB scoped-VMEM default (tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations
@@ -35,7 +37,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import LANES, PRECISION
+
 NEG_LARGE = -1e30
+
+
+def _write_block_max(res, m_i, bmax_ref, bidx_ref):
+    """Store this column block's residual max and its global column index.
+
+    Mosaic blocks must be (8, 128)-aligned or span the whole array, so the
+    per-block outputs are one lane row wide: each (1, LANES) block holds
+    its scalar broadcast across the lanes and ops.py reads lane 0.
+    """
+    mt = res.shape[1]
+    local = jax.lax.argmax(res[0], 0, jnp.int32)
+    bmax_ref[...] = jnp.full(bmax_ref.shape, jnp.max(res), bmax_ref.dtype)
+    bidx_ref[...] = jnp.full(bidx_ref.shape, local + m_i * mt,
+                             bidx_ref.dtype)
 
 
 def _kernel_real(q_ref, s_ref, acc_ref, norms_ref,
@@ -49,7 +67,8 @@ def _kernel_real(q_ref, s_ref, acc_ref, norms_ref,
         c_scr[...] = jnp.zeros_like(c_scr)
 
     c_scr[...] += jnp.dot(
-        q_ref[...], s_ref[...], preferred_element_type=jnp.float32
+        q_ref[...], s_ref[...], precision=PRECISION,
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(n_i == n_blocks - 1)
@@ -59,10 +78,7 @@ def _kernel_real(q_ref, s_ref, acc_ref, norms_ref,
         acc = acc_ref[...] + c * c
         acc_out_ref[...] = acc
         res = norms_ref[...] - acc
-        mt = res.shape[1]
-        bmax_ref[0, 0] = jnp.max(res)
-        local = jnp.argmax(res[0]).astype(jnp.int32)
-        bidx_ref[0, 0] = local + m_i * mt
+        _write_block_max(res, m_i, bmax_ref, bidx_ref)
 
 
 def _kernel_complex(qr_ref, qi_ref, sr_ref, si_ref, acc_ref, norms_ref,
@@ -82,10 +98,14 @@ def _kernel_complex(qr_ref, qi_ref, sr_ref, si_ref, acc_ref, norms_ref,
     sr = sr_ref[...]
     si = si_ref[...]
     # c = q^H S = (qr - i qi)^T (sr + i si)
-    cr_scr[...] += jnp.dot(qr, sr, preferred_element_type=jnp.float32)
-    cr_scr[...] += jnp.dot(qi, si, preferred_element_type=jnp.float32)
-    ci_scr[...] += jnp.dot(qr, si, preferred_element_type=jnp.float32)
-    ci_scr[...] -= jnp.dot(qi, sr, preferred_element_type=jnp.float32)
+    cr_scr[...] += jnp.dot(qr, sr, precision=PRECISION,
+                           preferred_element_type=jnp.float32)
+    cr_scr[...] += jnp.dot(qi, si, precision=PRECISION,
+                           preferred_element_type=jnp.float32)
+    ci_scr[...] += jnp.dot(qr, si, precision=PRECISION,
+                           preferred_element_type=jnp.float32)
+    ci_scr[...] -= jnp.dot(qi, sr, precision=PRECISION,
+                           preferred_element_type=jnp.float32)
 
     @pl.when(n_i == n_blocks - 1)
     def _():
@@ -96,16 +116,13 @@ def _kernel_complex(qr_ref, qi_ref, sr_ref, si_ref, acc_ref, norms_ref,
         acc = acc_ref[...] + cr * cr + ci * ci
         acc_out_ref[...] = acc
         res = norms_ref[...] - acc
-        mt = res.shape[1]
-        bmax_ref[0, 0] = jnp.max(res)
-        local = jnp.argmax(res[0]).astype(jnp.int32)
-        bidx_ref[0, 0] = local + m_i * mt
+        _write_block_max(res, m_i, bmax_ref, bidx_ref)
 
 
 @functools.partial(
     jax.jit, static_argnames=("nt", "mt", "interpret")
 )
-def greedy_update_real(q, S, acc, norms_sq, nt: int = 512, mt: int = 1024,
+def greedy_update_real(q, S, acc, norms_sq, nt: int = 256, mt: int = 1024,
                        interpret: bool = True):
     """Real-dtype fused update on padded inputs (see ops.py for padding).
 
@@ -126,14 +143,14 @@ def greedy_update_real(q, S, acc, norms_sq, nt: int = 512, mt: int = 1024,
         out_specs=[
             pl.BlockSpec((1, mt), lambda m, n: (0, m)),
             pl.BlockSpec((1, mt), lambda m, n: (0, m)),
-            pl.BlockSpec((1, 1), lambda m, n: (0, m)),
-            pl.BlockSpec((1, 1), lambda m, n: (0, m)),
+            pl.BlockSpec((1, LANES), lambda m, n: (0, m)),
+            pl.BlockSpec((1, LANES), lambda m, n: (0, m)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, M), S.dtype),
             jax.ShapeDtypeStruct((1, M), jnp.float32),
-            jax.ShapeDtypeStruct((1, M // mt), jnp.float32),
-            jax.ShapeDtypeStruct((1, M // mt), jnp.int32),
+            jax.ShapeDtypeStruct((1, M // mt * LANES), jnp.float32),
+            jax.ShapeDtypeStruct((1, M // mt * LANES), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((1, mt), jnp.float32)],
         interpret=interpret,
@@ -145,7 +162,7 @@ def greedy_update_real(q, S, acc, norms_sq, nt: int = 512, mt: int = 1024,
     jax.jit, static_argnames=("nt", "mt", "interpret")
 )
 def greedy_update_complex(qr, qi, Sr, Si, acc, norms_sq,
-                          nt: int = 512, mt: int = 1024,
+                          nt: int = 256, mt: int = 1024,
                           interpret: bool = True):
     """Complex fused update on split re/im planes (padded; see ops.py)."""
     N, M = Sr.shape
@@ -165,15 +182,15 @@ def greedy_update_complex(qr, qi, Sr, Si, acc, norms_sq,
             pl.BlockSpec((1, mt), lambda m, n: (0, m)),
             pl.BlockSpec((1, mt), lambda m, n: (0, m)),
             pl.BlockSpec((1, mt), lambda m, n: (0, m)),
-            pl.BlockSpec((1, 1), lambda m, n: (0, m)),
-            pl.BlockSpec((1, 1), lambda m, n: (0, m)),
+            pl.BlockSpec((1, LANES), lambda m, n: (0, m)),
+            pl.BlockSpec((1, LANES), lambda m, n: (0, m)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, M), Sr.dtype),
             jax.ShapeDtypeStruct((1, M), Sr.dtype),
             jax.ShapeDtypeStruct((1, M), jnp.float32),
-            jax.ShapeDtypeStruct((1, M // mt), jnp.float32),
-            jax.ShapeDtypeStruct((1, M // mt), jnp.int32),
+            jax.ShapeDtypeStruct((1, M // mt * LANES), jnp.float32),
+            jax.ShapeDtypeStruct((1, M // mt * LANES), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, mt), jnp.float32),

@@ -26,7 +26,7 @@ def greedy_update(
     S: jax.Array,
     acc: jax.Array,
     norms_sq: jax.Array,
-    nt: int = 512,
+    nt: int = 256,
     mt: int = 1024,
     interpret: bool | None = None,
 ):
@@ -76,9 +76,11 @@ def greedy_update(
         )
         c = c[0, :M]
 
-    # Final reduction over the per-block maxima (tiny: M/mt entries).
-    blk = jnp.argmax(bmax[0])
-    max_res = bmax[0, blk]
-    argmax = bidx[0, blk]
+    # Final reduction over the per-block maxima (tiny: M/mt entries, one
+    # per lane row; see kernel._write_block_max).
+    bmax, bidx = bmax[0, ::LANES], bidx[0, ::LANES]
+    blk = jnp.argmax(bmax)
+    max_res = bmax[blk]
+    argmax = bidx[blk]
     acc_out = acc_out[0, :M].astype(acc.dtype)
     return c, acc_out, max_res.astype(norms_sq.dtype), argmax

@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import matmul as _mm
+
 
 def greedy_update_ref(q: jax.Array, S: jax.Array, acc: jax.Array,
                       norms_sq: jax.Array):
@@ -22,7 +24,7 @@ def greedy_update_ref(q: jax.Array, S: jax.Array, acc: jax.Array,
       max_res:  ()  max_i (norms_sq - acc_out)_i.
       argmax:   ()  int32 argmax_i of the residual.
     """
-    c = q.conj() @ S
+    c = _mm(q.conj(), S)
     acc_out = acc + jnp.abs(c) ** 2
     res = norms_sq - acc_out
     return c, acc_out, jnp.max(res), jnp.argmax(res).astype(jnp.int32)

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import PRECISION
+
 
 def _proj_kernel(vt_ref, q_ref, c_ref, c_scr):
     n_i = pl.program_id(1)
@@ -46,7 +48,8 @@ def _proj_kernel(vt_ref, q_ref, c_ref, c_scr):
 
     # (p, nt) @ (nt, kt) -> (p, kt): the panel's C^T tile
     c_scr[...] += jnp.dot(
-        vt_ref[...], q_ref[...], preferred_element_type=jnp.float32
+        vt_ref[...], q_ref[...], precision=PRECISION,
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(n_i == n_blocks - 1)
@@ -64,7 +67,8 @@ def _update_kernel(vt_ref, q_ref, c_ref, out_ref, p_scr):
 
     # (p, kt) @ (kt, nt) -> (p, nt): the rank-K panel update tile
     p_scr[...] += jnp.dot(
-        c_ref[...], q_ref[...].T, preferred_element_type=jnp.float32
+        c_ref[...], q_ref[...].T, precision=PRECISION,
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(k_i == k_blocks - 1)

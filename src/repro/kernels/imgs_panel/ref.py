@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels.common import matmul as _mm
+
 
 def imgs_panel_ref(V: jax.Array, Q: jax.Array):
     """One classical-GS pass on a whole candidate panel.
@@ -20,5 +22,5 @@ def imgs_panel_ref(V: jax.Array, Q: jax.Array):
 
     Returns (V', C) with C: (K, p).
     """
-    C = Q.conj().T @ V
-    return V - Q @ C, C
+    C = _mm(Q.conj().T, V)
+    return V - _mm(Q, C), C

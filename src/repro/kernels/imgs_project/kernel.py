@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import PRECISION
+
 
 def _proj_kernel(v_ref, q_ref, c_ref, c_scr):
     n_i = pl.program_id(1)
@@ -37,7 +39,8 @@ def _proj_kernel(v_ref, q_ref, c_ref, c_scr):
         c_scr[...] = jnp.zeros_like(c_scr)
 
     c_scr[...] += jnp.dot(
-        v_ref[...], q_ref[...], preferred_element_type=jnp.float32
+        v_ref[...], q_ref[...], precision=PRECISION,
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(n_i == n_blocks - 1)
@@ -55,7 +58,8 @@ def _update_kernel(v_ref, q_ref, c_ref, out_ref, p_scr):
 
     # (1, kt) @ (kt, nt) -> (1, nt)
     p_scr[...] += jnp.dot(
-        c_ref[...], q_ref[...].T, preferred_element_type=jnp.float32
+        c_ref[...], q_ref[...].T, precision=PRECISION,
+        preferred_element_type=jnp.float32
     )
 
     @pl.when(k_i == k_blocks - 1)

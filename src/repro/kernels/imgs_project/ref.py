@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels.common import matmul as _mm
+
 
 def imgs_project_ref(v: jax.Array, Q: jax.Array):
     """One classical-GS pass: c = Q^H v; v' = v - Q c.
@@ -14,5 +16,5 @@ def imgs_project_ref(v: jax.Array, Q: jax.Array):
 
     Returns (v', c) with c: (K,).
     """
-    c = Q.conj().T @ v
-    return v - Q @ c, c
+    c = _mm(Q.conj().T, v)
+    return v - _mm(Q, c), c

@@ -1,6 +1,8 @@
 import os
 
 if os.environ.get("REPRO_DRYRUN"):  # must precede any jax import
+    # compile-only on 512 host devices: never take an attached chip
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Distributed greedy-reduction launcher (the paper's production job).
@@ -10,7 +12,9 @@ Two modes:
   real   — build the snapshot matrix column-sharded over the current mesh
            (each device generates its own parameter slice, greedycpp-style),
            run distributed RB-greedy with periodic checkpointing, export
-           basis/pivots/EI nodes.
+           basis/pivots/EI nodes.  The full-size problem is the paper's
+           N=10,000 complex64 rows at one chip's column count
+           (configs/gw_greedy.py ``one_chip``); ``--small`` is a toy.
 
   dryrun — REPRO_DRYRUN=1: lower + compile one distributed-greedy step at
            the Blue Waters flagship shape (10,000 x 3,276,800 complex64,
@@ -32,8 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import make_auto_mesh
-from repro.configs.gw_greedy import CONFIG as GW_CONFIG, reduced as gw_reduced
+from repro.compat import enable_compile_cache, make_auto_mesh
+from repro.configs.gw_greedy import CONFIG as GW_CONFIG, one_chip, reduced
 from repro.core.distributed import (
     DistGreedyState,
     make_dist_greedy_step,
@@ -111,30 +115,46 @@ def dryrun(mesh_kind: str, out_dir: str):
     return rec
 
 
+def gw_problem(small: bool):
+    """The launcher's workload and its grids: ``(workload, f, m1, m2)``."""
+    from repro.gw import chirp_grid, frequency_grid
+
+    wl = reduced() if small else one_chip()
+    f = frequency_grid(20.0, 512.0, wl.n_rows)
+    m1, m2 = chirp_grid(n_mc=wl.n_cols // 16, n_eta=16)
+    return wl, f, m1, m2
+
+
+def column_sharding() -> NamedSharding:
+    """S's layout for the distributed build: columns over every device."""
+    mesh = make_auto_mesh((len(jax.devices()),), ("cols",))
+    return NamedSharding(mesh, P(None, ("cols",)))
+
+
 def real_run(tau: float, out: str, small: bool, chunk: int = 16,
              backend: str | None = None, strategy: str = "distributed",
              workdir: str | None = None, resume: bool = False,
              tile_m: int = 4096):
+    """Build, save and export one basis; returns the ReducedBasis."""
     from repro.api import ReductionSpec, build_basis
     from repro.checkpoint import save_checkpoint
     from repro.data.providers import WaveformProvider
-    from repro.gw import build_snapshot_matrix, chirp_grid, frequency_grid
+    from repro.gw import build_snapshot_matrix
 
-    wl = gw_reduced() if small else GW_CONFIG
-    f = frequency_grid(20.0, 512.0, wl.n_rows)
-    n_cols = wl.n_cols
-    m1, m2 = chirp_grid(n_mc=n_cols // 16, n_eta=16)
+    wl, f, m1, m2 = gw_problem(small)
 
     common = dict(
         tau=wl.tau, max_k=wl.max_k, chunk=chunk, backend=backend,
         workdir=workdir, resume=resume,
     )
     if strategy == "distributed":
-        devs = jax.devices()
-        mesh = make_auto_mesh((len(devs),), ("cols",))
-        sharding = NamedSharding(mesh, P(None, ("cols",)))
+        sharding = column_sharding()
+        mesh = sharding.mesh
         S = build_snapshot_matrix(f, m1, m2, dtype=jnp.complex64,
                                   sharding=sharding)
+        print("S column shards: " + ", ".join(
+            f"{s.device.id}:{s.index[1].start}-{s.index[1].stop} "
+            f"({s.data.nbytes} B)" for s in S.addressable_shards))
         if workdir is None:
             # Legacy standalone checkpointing; with a workdir the build
             # lifecycle owns its own <workdir>/build/ checkpoints.
@@ -183,6 +203,7 @@ def real_run(tau: float, out: str, small: bool, chunk: int = 16,
     ei = basis.eim()
     np.save(os.path.join(out, "ei_nodes.npy"), np.asarray(ei.nodes))
     print(f"exported ReducedBasis artifact + {k} EI nodes to {out}")
+    return basis
 
 
 def main():
@@ -216,6 +237,7 @@ def main():
     ap.add_argument("--tile-m", type=int, default=4096,
                     help="streamed tile width in columns")
     args = ap.parse_args()
+    enable_compile_cache()
     if os.environ.get("REPRO_DRYRUN"):
         dryrun(args.mesh, args.out)
     else:

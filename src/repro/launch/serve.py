@@ -35,6 +35,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compat import enable_compile_cache
 from repro.configs import get_config, get_reduced
 from repro.models import api
 from repro.serving import ServeEngine
@@ -61,17 +62,19 @@ def _request_pool(basis, eim, pool: int, seed: int):
     Returns ``(at_nodes (k, pool), full (N, pool))`` — ``full`` is the
     exact interpolant of each request (requests lie in span(Q), where the
     empirical interpolant is exact up to the interpolation solve), used
-    as the per-request correctness reference."""
-    import jax.numpy as jnp
-
+    as the per-request correctness reference.  It is computed in float64
+    NumPy on the host from the host copy of Q, independent of the device
+    that serves, so a precision fault there shows as error."""
     rng = np.random.default_rng(seed)
+    Q = np.asarray(basis.Q)
     coeff = rng.standard_normal((basis.k, pool))
-    if jnp.iscomplexobj(basis.Q):
+    if np.iscomplexobj(Q):
         coeff = coeff + 1j * rng.standard_normal((basis.k, pool))
-    full = np.asarray(basis.Q @ jnp.asarray(coeff.astype(
-        np.asarray(basis.Q).dtype)))
+        full = Q.astype(np.complex128) @ coeff
+    else:
+        full = Q.astype(np.float64) @ coeff
     nodes = np.asarray(eim.nodes)
-    return full[nodes, :], full
+    return full[nodes, :].astype(Q.dtype), full
 
 
 def serve_basis(basis_dirs, *, max_batch: int = 64,
@@ -86,7 +89,10 @@ def serve_basis(basis_dirs, *, max_batch: int = 64,
                 breaker_cooldown_s: float = 5.0,
                 max_restarts: int = 3):
     """Serve synthetic ROQ traffic over the given artifacts; returns the
-    final engine stats dict (plus ``max_err`` / ``served`` keys)."""
+    final engine stats dict plus ``served``, ``max_err`` (largest error
+    against the float64 host reference) and ``max_ref`` (largest reference
+    entry).  ``stats["counters"]["worker_deaths"]`` counts worker deaths,
+    restarted or not."""
     from repro.serving import (
         CircuitOpenError, QueueFullError, QuotaExceededError, RestartPolicy,
         ROQEngine, ShedError)
@@ -153,13 +159,15 @@ def serve_basis(basis_dirs, *, max_batch: int = 64,
     engine.close(drain=True)
     wall = time.perf_counter() - t0
 
-    max_err = 0.0
+    max_err = max_ref = 0.0
     for fut, bid, col in futures:
         out = fut.result()
         ref = pools[bid][1][:, col]
         max_err = max(max_err, float(np.max(np.abs(out - ref))))
+        max_ref = max(max_ref, float(np.max(np.abs(ref))))
     stats = engine.stats()
     stats["max_err"] = max_err
+    stats["max_ref"] = max_ref
     stats["served"] = len(futures)
     stats["submit_rejected"] = rejected
     stats["submit_shed"] = shed
@@ -234,8 +242,9 @@ def main(argv=None):
                          "(0 disables: a dead worker latches unhealthy)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.basis:
-        return serve_basis(
+        stats = serve_basis(
             args.basis, max_batch=args.max_batch,
             max_wait_ms=args.max_wait_ms, requests=args.requests,
             duration=args.duration, queue_depth=args.queue_depth,
@@ -246,6 +255,10 @@ def main(argv=None):
             breaker_threshold=args.breaker_threshold,
             breaker_cooldown_s=args.breaker_cooldown_s,
             max_restarts=args.max_restarts)
+        deaths = stats["counters"]["worker_deaths"]
+        if deaths:
+            raise SystemExit(f"serving worker died {deaths} time(s)")
+        return stats
     if not args.arch:
         ap.error("--arch is required unless --basis is given")
 

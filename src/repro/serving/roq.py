@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.common import matmul as _mm
 from repro.serving.admission import AdmissionController, CircuitBreakerBoard
 from repro.serving.health import (
     EngineUnhealthyError,
@@ -114,12 +115,12 @@ def batch_bucket(n: int) -> int:
 # device-committed interpolant planes.
 @jax.jit
 def _apply_real(B, F):
-    return B @ F
+    return _mm(B, F)
 
 
 @jax.jit
 def _apply_split(Br, Bi, Fr, Fi):
-    return Br @ Fr - Bi @ Fi, Br @ Fi + Bi @ Fr
+    return _mm(Br, Fr) - _mm(Bi, Fi), _mm(Br, Fi) + _mm(Bi, Fr)
 
 
 def _eval_planes(planes, Fp: np.ndarray) -> np.ndarray:

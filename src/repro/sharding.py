@@ -103,18 +103,16 @@ def seq_allgather(x: jax.Array) -> jax.Array:
     mesh = current_mesh()
     if mesh is None or "model" not in mesh.axis_names:
         return x
-    from jax.experimental.shard_map import shard_map
-
     dp = _dp_axes(mesh)
 
     def local(xl):
         return jax.lax.all_gather(xl, "model", axis=1, tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=P(dp, "model", None),
         out_specs=P(dp, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x)
 
 
@@ -131,8 +129,6 @@ def tp_rs_matmul(h: jax.Array, w: jax.Array) -> jax.Array:
     mesh = current_mesh()
     if mesh is None or "model" not in mesh.axis_names:
         return h @ w
-    from jax.experimental.shard_map import shard_map
-
     dp = _dp_axes(mesh)
 
     def local(hl, wl):
@@ -141,11 +137,11 @@ def tp_rs_matmul(h: jax.Array, w: jax.Array) -> jax.Array:
             part, "model", scatter_dimension=1, tiled=True
         )
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, "model"), P("model", None)),
         out_specs=P(dp, "model", None),
-        check_rep=False,
+        check_vma=False,
     )(h, w)
 
 
@@ -163,19 +159,17 @@ def tp_ag_matmuls(x: jax.Array, *ws: jax.Array):
     mesh = current_mesh()
     if mesh is None or "model" not in mesh.axis_names:
         return tuple(x @ w for w in ws)
-    from jax.experimental.shard_map import shard_map
-
     dp = _dp_axes(mesh)
 
     def local(xl, *wls):
         xg = jax.lax.all_gather(xl, "model", axis=1, tiled=True)
         return tuple(xg @ wl for wl in wls)
 
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, "model", None),) + tuple(
             P(None, "model") for _ in ws),
         out_specs=tuple(P(dp, None, "model") for _ in ws),
-        check_rep=False,
+        check_vma=False,
     )(x, *ws)
     return out

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map
-
 from repro.models import transformer as tfm
 from repro.models.layers import rms_norm
 
@@ -131,10 +129,10 @@ def make_pipeline_forward(cfg, mesh: Mesh, n_micro: int):
         P(*([None] * 3)),                     # tokens (n_micro, B, S)
         P(*([None] * 3)),
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=in_specs, out_specs=P("pod"),
-        check_rep=False,
+        check_vma=False,
     )
 
     def loss_fn(embed, blocks, norm_w, lm_head, tokens, labels):

@@ -146,6 +146,56 @@ def test_auto_respects_env_budget(monkeypatch):
     assert device_memory_budget() == 12345
 
 
+@pytest.mark.parametrize("stats", [{"bytes_limit": 16 << 30}, {}, None])
+def test_tpu_budget_comes_from_the_device(monkeypatch, stats):
+    """On a TPU the budget is the device's bytes_limit, or an error: never
+    the host-RAM fallback."""
+    import repro.api.build as build_mod
+
+    class FakeTpu:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+        def memory_stats(self):
+            return stats
+
+    monkeypatch.delenv("REPRO_DEVICE_MEM_BUDGET", raising=False)
+    monkeypatch.setattr(build_mod.jax, "devices", lambda: [FakeTpu()])
+    if stats:
+        assert build_mod.device_memory_budget() == 16 << 30
+    else:
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            build_mod.device_memory_budget()
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    cache sits at the fixed <repo>/.jax_cache."""
+    import os
+
+    from repro.compat import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = enable_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            assert got == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 # --------------------------------------- auto DRAM-roofline (PR 4) ----
 
 
