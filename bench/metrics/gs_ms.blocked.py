@@ -1,0 +1,6 @@
+"""``gs_ms`` in the blocked build cell, where it moves ``build_s.blocked``:
+the same reading (``bench/metrics/gs_ms.py``)."""
+
+from bench.harness import load_reader
+
+read = load_reader("gs_ms").read

@@ -1,0 +1,32 @@
+"""Published peaks of the chips the benchmark runs on, keyed by the
+``device_kind`` JAX reports.  A device that is not here is an error: a
+roofline share is never taken against a guessed peak.
+
+TPU v5e (JAX: "TPU v5 lite"): Google Cloud documentation, "TPU v5e"
+(cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 393 TOP/s int8,
+16 GB of HBM at 819 GB/s per chip.  No float32 peak is published, so no
+float32 compute roof is assumed: the kernels measured here are read
+against the HBM roof only.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peaks of ``device_kind``; raises ``KeyError`` for any other."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
