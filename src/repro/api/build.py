@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.api.artifact import ReducedBasis
 from repro.api.spec import STRATEGIES, ReductionSpec
+from repro.spans import span
 
 logger = logging.getLogger("repro.api")
 
@@ -307,10 +308,11 @@ def _trim_greedy(res, extras=None):
     stop = getattr(res, "stop", None)
     if stop is not None:
         extras["stop"] = STOP_NAMES.get(int(stop), str(int(stop)))
-    return (res.Q[:, :k], np.asarray(res.pivots[:k]),
-            np.asarray(res.errs[:k]),
-            None if res.R is None else np.asarray(res.R[:k]), k,
-            extras)
+    with span("repro.build.to_host"):
+        return (res.Q[:, :k], np.asarray(res.pivots[:k]),
+                np.asarray(res.errs[:k]),
+                None if res.R is None else np.asarray(res.R[:k]), k,
+                extras)
 
 
 def _build_greedy(spec, S, ckpt_dir=None):
@@ -537,7 +539,11 @@ def build_basis(spec: ReductionSpec | None = None,
             f"build_basis takes a ReductionSpec (or keyword args), got "
             f"{type(spec).__name__}"
         )
+    with span("repro.build", strategy=spec.strategy):
+        return _build_basis(spec)
 
+
+def _build_basis(spec: ReductionSpec):
     # Many-basis workloads return a set; decide BEFORE touching providers
     # (a stacked 3-D source is not a valid single-basis provider).
     if spec.strategy == "batched":

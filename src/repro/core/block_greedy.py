@@ -63,6 +63,7 @@ from repro.core.greedy import (
     resident_state_from_tree,
     save_resident_checkpoint,
 )
+from repro.spans import span, traced
 
 
 def _ortho_block(S, Q, top_idx, slots, p, kappa, max_passes, eps, scale,
@@ -380,6 +381,7 @@ def _compact_result(state, max_k: int, stop: int = STOP_NONE) -> GreedyResult:
     )
 
 
+@traced("repro.driver")
 def _rb_greedy_block_impl(
     S,
     tau: float,
@@ -484,49 +486,50 @@ def _rb_greedy_block_impl(
     trajectory = [] if diagnostics is not None else None
     while not done and int(state.k) + p_live <= max_slots:
         slots_before = int(state.k)
-        state, n_done, stop = chunk_fn(
-            S, state, tau_d, scale_d, ref_sq_d, safety_d,
-            chunk=chunk, p=p_live, kappa=kappa, max_passes=max_passes,
-            backend=backend, check_refresh=(refresh == "auto"),
-            panel=panel,
-        )
-        if callback is not None:
-            callback(state)
-        stop = int(stop)
-        if adaptive or trajectory is not None:
-            slots_added = int(state.k) - slots_before
-            rejected = (
-                int(np.count_nonzero(np.asarray(
-                    state.pivots[slots_before:slots_before + slots_added]
-                ) < 0)) if slots_added else 0
+        with span("repro.driver.chunk", k=slots_before):
+            state, n_done, stop = chunk_fn(
+                S, state, tau_d, scale_d, ref_sq_d, safety_d,
+                chunk=chunk, p=p_live, kappa=kappa, max_passes=max_passes,
+                backend=backend, check_refresh=(refresh == "auto"),
+                panel=panel,
             )
-            if trajectory is not None:
-                trajectory.append({"slots": slots_before, "p": p_live,
-                                   "rejected": rejected})
-            if adaptive and slots_added:
-                rate = rejected / slots_added
-                if rate > 0.25 and p_live > 1:
-                    # staleness bites: most in-block picks collapse once
-                    # the earlier ones land — narrow the panel
-                    p_live = max(1, p_live // 2)
-                elif rejected == 0 and p_live < p:
-                    p_live = min(p, p_live * 2)
-        if stop == STOP_TAU or stop == STOP_RANK:
-            done, final_stop = True, stop
-        elif stop == STOP_REFRESH:
-            state = greedy_refresh(S, state)
-            ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
-            ref_sq_d = jnp.asarray(ref_sq, rdt)
-            if ref_sq ** 0.5 < tau:
-                done, final_stop = True, STOP_TAU
-            elif ref_sq ** 0.5 <= floor_estimate(eps, scale, int(state.k)):
-                done, final_stop = True, STOP_FLOOR
-        if not done and int(state.k) + p_live > max_slots:
-            done = True  # out of slots; final_stop stays STOP_NONE
-        if checkpoint_dir is not None:
-            seq = save_resident_checkpoint(
-                checkpoint_dir, seq, state, ref_sq, scale, done, final_stop,
-                extra={"p_live": p_live})
+            if callback is not None:
+                callback(state)
+            stop = int(stop)
+            if adaptive or trajectory is not None:
+                slots_added = int(state.k) - slots_before
+                rejected = (
+                    int(np.count_nonzero(np.asarray(
+                        state.pivots[slots_before:slots_before + slots_added]
+                    ) < 0)) if slots_added else 0
+                )
+                if trajectory is not None:
+                    trajectory.append({"slots": slots_before, "p": p_live,
+                                       "rejected": rejected})
+                if adaptive and slots_added:
+                    rate = rejected / slots_added
+                    if rate > 0.25 and p_live > 1:
+                        # staleness bites: most in-block picks collapse once
+                        # the earlier ones land — narrow the panel
+                        p_live = max(1, p_live // 2)
+                    elif rejected == 0 and p_live < p:
+                        p_live = min(p, p_live * 2)
+            if stop == STOP_TAU or stop == STOP_RANK:
+                done, final_stop = True, stop
+            elif stop == STOP_REFRESH:
+                state = greedy_refresh(S, state)
+                ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
+                ref_sq_d = jnp.asarray(ref_sq, rdt)
+                if ref_sq ** 0.5 < tau:
+                    done, final_stop = True, STOP_TAU
+                elif ref_sq ** 0.5 <= floor_estimate(eps, scale, int(state.k)):
+                    done, final_stop = True, STOP_FLOOR
+            if not done and int(state.k) + p_live > max_slots:
+                done = True  # out of slots; final_stop stays STOP_NONE
+            if checkpoint_dir is not None:
+                seq = save_resident_checkpoint(
+                    checkpoint_dir, seq, state, ref_sq, scale, done,
+                    final_stop, extra={"p_live": p_live})
     if diagnostics is not None:
         diagnostics["p_trajectory"] = trajectory
     return _compact_result(state, max_k, final_stop)

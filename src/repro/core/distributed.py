@@ -57,6 +57,7 @@ from repro.core.greedy import (
     load_resident_checkpoint,
     panel_imgs_orthogonalize,
 )
+from repro.spans import span, traced
 
 
 class DistGreedyState(NamedTuple):
@@ -536,6 +537,7 @@ def make_dist_refresh(mesh: Mesh):
     return jax.jit(sharded, donate_argnums=(1,))
 
 
+@traced("repro.driver")
 def distributed_greedy(
     S,
     tau: float,
@@ -636,40 +638,42 @@ def distributed_greedy(
     ref_sq_d = jnp.asarray(ref_sq, rdt)
     k = int(state.k)
     while not done and k < max_k:
-        state, n_done, stop = chunk_fn(
-            S, state, tau_d, scale_d, ref_sq_d, safety_d,
-        )
-        k = int(state.k)
-        if callback is not None:
-            callback(state)
-        stop = int(stop)
-        if stop == STOP_TAU:
-            k -= 1
-            state = state._replace(
-                k=jnp.asarray(k, jnp.int32),
-                Q=state.Q.at[:, k].set(0),
-                pivots=state.pivots.at[k].set(-1),
+        with span("repro.driver.chunk", k=k):
+            state, n_done, stop = chunk_fn(
+                S, state, tau_d, scale_d, ref_sq_d, safety_d,
             )
-            done, final_stop = True, STOP_TAU
-        elif stop == STOP_RANK:
-            k -= 1
-            state = state._replace(k=jnp.asarray(k, jnp.int32))
-            done, final_stop = True, STOP_RANK
-        elif stop == STOP_REFRESH:
-            state = refresh_fn(S, state)
-            ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
-            ref_sq_d = jnp.asarray(ref_sq, rdt)
-            if ref_sq ** 0.5 < tau:
+            k = int(state.k)
+            if callback is not None:
+                callback(state)
+            stop = int(stop)
+            if stop == STOP_TAU:
+                k -= 1
+                state = state._replace(
+                    k=jnp.asarray(k, jnp.int32),
+                    Q=state.Q.at[:, k].set(0),
+                    pivots=state.pivots.at[k].set(-1),
+                )
                 done, final_stop = True, STOP_TAU
-            elif ref_sq ** 0.5 <= floor_estimate(eps, scale, k):
-                done, final_stop = True, STOP_FLOOR
-        if not done and k >= max_k:
-            done = True  # ran to capacity; final_stop stays STOP_NONE
-        # (no n_done check: the chunk cond guarantees >= 1 iteration, and
-        # reading it back would add a host sync per chunk)
-        if checkpoint_dir is not None:
-            seq = _save_dist_checkpoint(
-                checkpoint_dir, seq, state, ref_sq, scale, done, final_stop)
+            elif stop == STOP_RANK:
+                k -= 1
+                state = state._replace(k=jnp.asarray(k, jnp.int32))
+                done, final_stop = True, STOP_RANK
+            elif stop == STOP_REFRESH:
+                state = refresh_fn(S, state)
+                ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
+                ref_sq_d = jnp.asarray(ref_sq, rdt)
+                if ref_sq ** 0.5 < tau:
+                    done, final_stop = True, STOP_TAU
+                elif ref_sq ** 0.5 <= floor_estimate(eps, scale, k):
+                    done, final_stop = True, STOP_FLOOR
+            if not done and k >= max_k:
+                done = True  # ran to capacity; final_stop stays STOP_NONE
+            # (no n_done check: the chunk cond guarantees >= 1 iteration, and
+            # reading it back would add a host sync per chunk)
+            if checkpoint_dir is not None:
+                seq = _save_dist_checkpoint(
+                    checkpoint_dir, seq, state, ref_sq, scale, done,
+                    final_stop)
     return GreedyResult(
         Q=state.Q, R=state.R, pivots=state.pivots, errs=state.errs,
         k=state.k, n_ortho_passes=jnp.zeros_like(state.pivots),
@@ -741,27 +745,29 @@ def _distributed_block_greedy(
     safety_d = jnp.asarray(refresh_safety, rdt)
     ref_sq_d = jnp.asarray(ref_sq, rdt)
     while not done and int(state.k) + p <= max_slots:
-        state, n_done, stop = chunk_fn(
-            S, state, tau_d, scale_d, ref_sq_d, safety_d,
-        )
-        if callback is not None:
-            callback(state)
-        stop = int(stop)
-        if stop == STOP_TAU or stop == STOP_RANK:
-            done, final_stop = True, stop
-        elif stop == STOP_REFRESH:
-            state = refresh_fn(S, state)
-            ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
-            ref_sq_d = jnp.asarray(ref_sq, rdt)
-            if ref_sq ** 0.5 < tau:
-                done, final_stop = True, STOP_TAU
-            elif ref_sq ** 0.5 <= floor_estimate(eps, scale, int(state.k)):
-                done, final_stop = True, STOP_FLOOR
-        if not done and int(state.k) + p > max_slots:
-            done = True  # out of slots; final_stop stays STOP_NONE
-        if checkpoint_dir is not None:
-            seq = _save_dist_checkpoint(
-                checkpoint_dir, seq, state, ref_sq, scale, done, final_stop)
+        with span("repro.driver.chunk", k=int(state.k)):
+            state, n_done, stop = chunk_fn(
+                S, state, tau_d, scale_d, ref_sq_d, safety_d,
+            )
+            if callback is not None:
+                callback(state)
+            stop = int(stop)
+            if stop == STOP_TAU or stop == STOP_RANK:
+                done, final_stop = True, stop
+            elif stop == STOP_REFRESH:
+                state = refresh_fn(S, state)
+                ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
+                ref_sq_d = jnp.asarray(ref_sq, rdt)
+                if ref_sq ** 0.5 < tau:
+                    done, final_stop = True, STOP_TAU
+                elif ref_sq ** 0.5 <= floor_estimate(eps, scale, int(state.k)):
+                    done, final_stop = True, STOP_FLOOR
+            if not done and int(state.k) + p > max_slots:
+                done = True  # out of slots; final_stop stays STOP_NONE
+            if checkpoint_dir is not None:
+                seq = _save_dist_checkpoint(
+                    checkpoint_dir, seq, state, ref_sq, scale, done,
+                    final_stop)
     # compact holes + cap at max_k: shared with the resident blocked driver
     from repro.core.block_greedy import _compact_result
 

@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.core import backend as _backend
 from repro.kernels.common import matmul as _mm
+from repro.spans import span, traced
 
 
 class GreedyResult(NamedTuple):
@@ -600,6 +601,7 @@ _greedy_chunk_donated = jax.jit(
 )
 
 
+@traced("repro.driver")
 def rb_greedy(
     S,
     tau: float,
@@ -697,54 +699,57 @@ def rb_greedy(
     ref_sq_d = jnp.asarray(ref_sq, rdt)
     k = int(state.k)
     while not done and k < max_k:
-        state, n_done, stop = chunk_fn(
-            S, state, tau_d, scale_d, ref_sq_d, safety_d,
-            chunk=chunk, kappa=kappa, max_passes=max_passes,
-            backend=backend, check_refresh=(refresh == "auto"),
-        )
-        k = int(state.k)
-        if callback is not None:
-            callback(state)
-        stop = int(stop)
-        if stop == STOP_RANK:
-            # Numerical-rank exhaustion: the pivot's true orthogonalization
-            # residual is rounding noise — adding it would inject a junk,
-            # non-orthogonal direction (Cor. 5.6 says rnorm == err in exact
-            # arithmetic; their divergence is the symptom).  Drop and stop.
-            k -= 1
-            state = _drop_last(state, k)
-            done, final_stop = True, STOP_RANK
-        elif stop == STOP_TAU:
-            # Last added basis was selected at an error already below tau:
-            # drop it to match Algorithm 3's while-condition semantics.
-            k -= 1
-            state = _drop_last(state, k)
-            done, final_stop = True, STOP_TAU
-        elif stop == STOP_REFRESH:
-            # Approaching the Eq.-(6.3) cancellation floor while still above
-            # tau: recompute exact residuals and rescale the reference.
-            state = greedy_refresh(S, state)
-            ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
-            ref_sq_d = jnp.asarray(ref_sq, rdt)
-            # The recorded err was floor noise; the *post-add* exact error
-            # decides whether any further basis is needed (keep this one).
-            if ref_sq ** 0.5 < tau:
+        with span("repro.driver.chunk", k=k):
+            state, n_done, stop = chunk_fn(
+                S, state, tau_d, scale_d, ref_sq_d, safety_d,
+                chunk=chunk, kappa=kappa, max_passes=max_passes,
+                backend=backend, check_refresh=(refresh == "auto"),
+            )
+            k = int(state.k)
+            if callback is not None:
+                callback(state)
+            stop = int(stop)
+            if stop == STOP_RANK:
+                # Numerical-rank exhaustion: the pivot's true orthogonalization
+                # residual is rounding noise — adding it would inject a junk,
+                # non-orthogonal direction (Cor. 5.6 says rnorm == err in exact
+                # arithmetic; their divergence is the symptom).  Drop and stop.
+                k -= 1
+                state = _drop_last(state, k)
+                done, final_stop = True, STOP_RANK
+            elif stop == STOP_TAU:
+                # Last added basis was selected at an error already below tau:
+                # drop it to match Algorithm 3's while-condition semantics.
+                k -= 1
+                state = _drop_last(state, k)
                 done, final_stop = True, STOP_TAU
-            elif ref_sq ** 0.5 <= floor_estimate(eps, scale, k):
-                # Exact residual parked at the achievable floor: tau is
-                # unreachable in this precision — stop gracefully rather
-                # than accept noise-amplified directions.
-                done, final_stop = True, STOP_FLOOR
-        if not done and k >= max_k:
-            done = True  # ran to capacity; final_stop stays STOP_NONE
-        # (no n_done check: the chunk cond guarantees >= 1 iteration, and
-        # reading it back would add a host sync per chunk)
-        if checkpoint_dir is not None:
-            # Save AFTER stop handling: the chunk always runs >= 1
-            # iteration, so a pre-handling snapshot of a finished build
-            # would grow extra bases on resume.
-            seq = save_resident_checkpoint(
-                checkpoint_dir, seq, state, ref_sq, scale, done, final_stop)
+            elif stop == STOP_REFRESH:
+                # Approaching the Eq.-(6.3) cancellation floor while still
+                # above tau: recompute exact residuals and rescale the
+                # reference.
+                state = greedy_refresh(S, state)
+                ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
+                ref_sq_d = jnp.asarray(ref_sq, rdt)
+                # The recorded err was floor noise; the *post-add* exact error
+                # decides whether any further basis is needed (keep this one).
+                if ref_sq ** 0.5 < tau:
+                    done, final_stop = True, STOP_TAU
+                elif ref_sq ** 0.5 <= floor_estimate(eps, scale, k):
+                    # Exact residual parked at the achievable floor: tau is
+                    # unreachable in this precision — stop gracefully rather
+                    # than accept noise-amplified directions.
+                    done, final_stop = True, STOP_FLOOR
+            if not done and k >= max_k:
+                done = True  # ran to capacity; final_stop stays STOP_NONE
+            # (no n_done check: the chunk cond guarantees >= 1 iteration, and
+            # reading it back would add a host sync per chunk)
+            if checkpoint_dir is not None:
+                # Save AFTER stop handling: the chunk always runs >= 1
+                # iteration, so a pre-handling snapshot of a finished build
+                # would grow extra bases on resume.
+                seq = save_resident_checkpoint(
+                    checkpoint_dir, seq, state, ref_sq, scale, done,
+                    final_stop)
     return GreedyResult(
         Q=state.Q, R=state.R, pivots=state.pivots, errs=state.errs,
         k=state.k, n_ortho_passes=state.n_passes, rnorms=state.rnorms,

@@ -181,6 +181,10 @@ def serve_basis(basis_dirs, *, max_batch: int = 64,
     if lat:
         print(f"  latency p50={lat['p50']:.3f}ms p95={lat['p95']:.3f}ms "
               f"p99={lat['p99']:.3f}ms (n={lat['n']})")
+    wait = stats["queue_wait_ms"]
+    if wait:
+        print(f"  queue wait p50={wait['p50']:.3f}ms "
+              f"p95={wait['p95']:.3f}ms (n={wait['n']})")
     print(f"  batches={stats['counters']['batches']} "
           f"occupancy={stats['batch_occupancy_mean']:.2f} "
           f"cache_hit_rate={stats['cache_hit_rate']:.2f} "

@@ -4,11 +4,12 @@ One :class:`ServingMetrics` instance rides along with each
 :class:`~repro.serving.roq.ROQEngine`.  Every event on the request path
 increments a counter here (submit / reject / timeout / error / complete,
 batch flushes, interpolant-cache hits and misses, router loads and
-evictions), per-request latencies and batch occupancies land in bounded
-reservoirs, and :meth:`snapshot` rolls the lot into a JSON-friendly dict
-with p50/p95/p99 latency via :func:`repro.timing.percentiles` — the same
-quantile code the load harness uses, so benchmark rows and engine
-snapshots can never disagree on method.
+evictions), per-request latencies, queue waits and batch occupancies land
+in bounded reservoirs, and :meth:`snapshot` rolls the lot into a
+JSON-friendly dict with p50/p95/p99 latency via
+:func:`repro.timing.percentiles` — the same quantile code the load harness
+uses, so benchmark rows and engine snapshots can never disagree on
+method.
 
 Thread-safety: the engine worker and any number of submitting threads
 touch the same instance, so every mutation takes the one internal lock.
@@ -20,7 +21,6 @@ from __future__ import annotations
 
 import collections
 import threading
-import time
 
 from repro.timing import percentiles
 
@@ -60,10 +60,10 @@ class ServingMetrics:
         self._lock = threading.Lock()
         self._counts = {name: 0 for name in COUNTERS}
         self._latency_s = collections.deque(maxlen=window)
+        self._wait_s = collections.deque(maxlen=window)
         self._occupancy = collections.deque(maxlen=window)
         self._queue_depth = 0
         self._gauges: dict[str, float] = {}
-        self._started = time.perf_counter()
 
     # ------------------------------------------------------------ events ----
     def count(self, name: str, n: int = 1) -> None:
@@ -73,6 +73,11 @@ class ServingMetrics:
     def observe_latency(self, seconds: float) -> None:
         with self._lock:
             self._latency_s.append(float(seconds))
+
+    def observe_wait(self, seconds: float) -> None:
+        """A request's wait from ``submit`` to the start of its flush."""
+        with self._lock:
+            self._wait_s.append(float(seconds))
 
     def observe_batch(self, size: int, bucket: int) -> None:
         """A flush of ``size`` live requests padded to ``bucket`` columns."""
@@ -104,27 +109,25 @@ class ServingMetrics:
         """Point-in-time rollup (JSON-serializable).
 
         ``latency_ms`` holds p50/p95/p99 over the recent-latency window
-        (``None`` before the first completion); ``throughput_rps`` is
-        completions per wall-second since construction — a coarse
-        whole-run rate, not a windowed one (the load harness measures its
-        own steady-state rates).
+        (``None`` before the first completion); ``queue_wait_ms`` holds
+        p50/p95 of the recent waits from ``submit`` to the start of the
+        request's flush (``None`` before the first flush).
         """
         with self._lock:
             counts = dict(self._counts)
             lat = list(self._latency_s)
+            wait = list(self._wait_s)
             occ = list(self._occupancy)
             depth = self._queue_depth
             gauges = dict(self._gauges)
-            elapsed = time.perf_counter() - self._started
         snap = {
             "counters": counts,
             "queue_depth": depth,
             "gauges": gauges,
             "latency_ms": None,
+            "queue_wait_ms": None,
             "batch_occupancy_mean": (sum(occ) / len(occ)) if occ else None,
             "cache_hit_rate": None,
-            "throughput_rps": counts["completed"] / elapsed
-            if elapsed > 0 else 0.0,
         }
         if lat:
             pct = percentiles(lat, (50.0, 95.0, 99.0))
@@ -134,6 +137,10 @@ class ServingMetrics:
                 "p99": pct[99.0] * 1e3,
                 "n": len(lat),
             }
+        if wait:
+            pct = percentiles(wait, (50.0, 95.0))
+            snap["queue_wait_ms"] = {"p50": pct[50.0] * 1e3,
+                                     "p95": pct[95.0] * 1e3, "n": len(wait)}
         probes = counts["cache_hits"] + counts["cache_misses"]
         if probes:
             snap["cache_hit_rate"] = counts["cache_hits"] / probes

@@ -66,6 +66,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import itertools
 import logging
 import os
 import queue
@@ -87,6 +88,7 @@ from repro.serving.health import (
 )
 from repro.serving.metrics import ServingMetrics
 from repro.serving.router import BasisRouter
+from repro.spans import span
 
 logger = logging.getLogger("repro.serving")
 
@@ -127,13 +129,16 @@ def _eval_planes(planes, Fp: np.ndarray) -> np.ndarray:
     """Evaluate the committed interpolant on a padded (k, bucket) batch."""
     if len(planes) == 1:
         (B,) = planes
-        return np.asarray(_apply_real(B, jnp.asarray(Fp)))
+        out = _apply_real(B, jnp.asarray(Fp))
+        with span("repro.serve.to_host"):
+            return np.asarray(out)
     Br, Bi = planes
     re, im = _apply_split(Br, Bi, jnp.asarray(np.ascontiguousarray(Fp.real)),
                           jnp.asarray(np.ascontiguousarray(Fp.imag)))
-    out = np.empty((re.shape[0], re.shape[1]), dtype=Fp.dtype)
-    out.real = np.asarray(re)
-    out.imag = np.asarray(im)
+    with span("repro.serve.to_host"):
+        out = np.empty((re.shape[0], re.shape[1]), dtype=Fp.dtype)
+        out.real = np.asarray(re)
+        out.imag = np.asarray(im)
     return out
 
 
@@ -203,9 +208,10 @@ class InterpolantCache:
                 planes = _commit_planes(eim.B)
                 if not retired:
                     self._planes[(basis_id, generation)] = planes
-        Fp = np.zeros((F.shape[0], bucket), dtype=F.dtype)
-        Fp[:, :b] = F
-        out = _eval_planes(planes, Fp)[:, :b]
+        with span("repro.serve.eval"):
+            Fp = np.zeros((F.shape[0], bucket), dtype=F.dtype)
+            Fp[:, :b] = F
+            out = _eval_planes(planes, Fp)[:, :b]
         with self._lock:
             if not retired:
                 self._warm.add(key)
@@ -357,6 +363,7 @@ class ROQEngine:
         self._wake = threading.Event()
         self._stop_backoff = threading.Event()
         self._batch_ordinal = 0
+        self._req_ordinal = itertools.count(1)
         self._batch_ewma_s = 0.0
         self._last_pressure_check = 0.0
         self._worker: Optional[threading.Thread] = None
@@ -380,6 +387,10 @@ class ROQEngine:
         (``QuotaExceededError``), hopeless deadline (``ShedError``), and
         a full queue (:class:`QueueFullError`).
         """
+        with span("repro.serve.submit", req=next(self._req_ordinal)):
+            return self._submit(basis_id, f_nodes, timeout_s, client_id)
+
+    def _submit(self, basis_id, f_nodes, timeout_s, client_id):
         if self._closed:
             raise EngineClosedError("engine is closed to new requests")
         if not self._health.healthy():
@@ -532,7 +543,8 @@ class ROQEngine:
         while True:
             if self._abort:
                 break
-            self._wake.wait(timeout=self._poll_s(pending))
+            with span("repro.serve.wait"):
+                self._wake.wait(timeout=self._poll_s(pending))
             self._wake.clear()
             if self._abort:
                 break
@@ -671,9 +683,17 @@ class ROQEngine:
 
     # ------------------------------------------------------------ flush ----
     def _flush(self, basis_id: str, reqs: list) -> None:
+        # ``batch`` is the ordinal the batch gets if any request survives
+        # to evaluation
+        with span("repro.serve.flush", batch=self._batch_ordinal + 1,
+                  size=len(reqs), bucket=batch_bucket(len(reqs))):
+            self._flush_batch(basis_id, reqs)
+
+    def _flush_batch(self, basis_id: str, reqs: list) -> None:
         now = time.perf_counter()
         live = []
         for r in reqs:
+            self.metrics.observe_wait(now - r.t_submit)
             if r.deadline is not None and now > r.deadline:
                 if _resolve(r.future, error=TimeoutError(
                         f"request waited past its "
@@ -683,16 +703,17 @@ class ROQEngine:
                 live.append(r)
         if not live:
             return
-        try:
-            entry = self.router.get_entry(basis_id)
-        except Exception as e:  # unknown id, unreadable artifact, ...
-            self.breakers.record_failure(basis_id)
-            for r in live:
-                if _resolve(r.future, error=e):
-                    self.metrics.count("errors")
-            return
-        basis, eim = entry.basis, entry.eim
-        dtype = np.asarray(basis.Q).dtype
+        with span("repro.serve.route"):
+            try:
+                entry = self.router.get_entry(basis_id)
+            except Exception as e:  # unknown id, unreadable artifact, ...
+                self.breakers.record_failure(basis_id)
+                for r in live:
+                    if _resolve(r.future, error=e):
+                        self.metrics.count("errors")
+                return
+            basis, eim = entry.basis, entry.eim
+            dtype = np.asarray(basis.Q).dtype
         good = []
         for r in live:
             if r.f.shape != (basis.k,):
@@ -710,7 +731,9 @@ class ROQEngine:
                 self.metrics.count("errors")
         if not good:
             return
-        F = np.stack([r.f for r in good], axis=1).astype(dtype, copy=False)
+        with span("repro.serve.stack"):
+            F = np.stack([r.f for r in good], axis=1)
+            F = F.astype(dtype, copy=False)
         self._batch_ordinal += 1
         # OUTSIDE the per-batch try: an injected death here escapes the
         # batching logic entirely and must be caught by the supervision
@@ -741,10 +764,11 @@ class ROQEngine:
             else 0.2 * dt + 0.8 * self._batch_ewma_s
         self.metrics.count("cache_hits" if warm else "cache_misses")
         self.metrics.observe_batch(len(good), bucket)
-        for i, r in enumerate(good):
-            if _resolve(r.future, result=out[:, i]):
-                self.metrics.count("completed")
-                self.metrics.observe_latency(t_done - r.t_submit)
+        with span("repro.serve.resolve"):
+            for i, r in enumerate(good):
+                if _resolve(r.future, result=out[:, i]):
+                    self.metrics.count("completed")
+                    self.metrics.observe_latency(t_done - r.t_submit)
 
     # ------------------------------------------------------ chaos hooks ----
     @staticmethod
