@@ -54,10 +54,11 @@ def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def pad_to(x, size, axis, value=0.0):
+def pad_to(x, size, axis):
+    """Zero-pad ``x`` along ``axis`` to length ``size``."""
     pad = size - x.shape[axis]
     if pad <= 0:
         return x
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
+    return jnp.pad(x, widths)

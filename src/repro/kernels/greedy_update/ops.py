@@ -1,9 +1,9 @@
 """Public jit'd wrapper for the fused greedy pivot-search update.
 
-Handles dtype dispatch (real vs complex planes), tile padding, and CPU
-interpret fallback.  The padded columns get ``norms_sq = -1e30`` so they can
-never win the argmax; padded rows are zeros so they are no-ops in the dot
-products.
+Handles dtype dispatch (real vs complex planes) and CPU interpret fallback.
+S goes to the kernel at its own shape (N, M), never padded: the kernel masks
+a ragged last tile on either axis (see kernel.py).  Only q is padded, with
+zeros, to a whole number of row tiles; acc and norms_sq keep their length M.
 """
 
 from __future__ import annotations
@@ -51,30 +51,26 @@ def greedy_update(
     N, M = S.shape
     nt = min(nt, _round_up(N, LANES))
     mt = min(mt, _round_up(M, LANES))
-    Np, Mp = _round_up(N, nt), _round_up(M, mt)
+    Np = _round_up(N, nt)
 
-    acc_p = _pad_to(acc[None, :].astype(jnp.float32), Mp, 1)
-    norms_p = _pad_to(
-        norms_sq[None, :].astype(jnp.float32), Mp, 1, value=_k.NEG_LARGE
-    )
+    acc_2d = acc[None, :].astype(jnp.float32)
+    norms_2d = norms_sq[None, :].astype(jnp.float32)
 
     if jnp.iscomplexobj(S):
         plane = jnp.float32 if S.dtype == jnp.complex64 else jnp.float64
         qr = _pad_to(q.real[None, :].astype(plane), Np, 1)
         qi = _pad_to(q.imag[None, :].astype(plane), Np, 1)
-        Sr = _pad_to(_pad_to(S.real.astype(plane), Np, 0), Mp, 1)
-        Si = _pad_to(_pad_to(S.imag.astype(plane), Np, 0), Mp, 1)
         cr, ci, acc_out, bmax, bidx = _k.greedy_update_complex(
-            qr, qi, Sr, Si, acc_p, norms_p, nt=nt, mt=mt, interpret=interpret
+            qr, qi, S.real.astype(plane), S.imag.astype(plane), acc_2d,
+            norms_2d, nt=nt, mt=mt, interpret=interpret
         )
-        c = (cr[0, :M] + 1j * ci[0, :M]).astype(S.dtype)
+        c = (cr[0] + 1j * ci[0]).astype(S.dtype)
     else:
         qp = _pad_to(q[None, :].astype(S.dtype), Np, 1)
-        Sp = _pad_to(_pad_to(S, Np, 0), Mp, 1)
         c, acc_out, bmax, bidx = _k.greedy_update_real(
-            qp, Sp, acc_p, norms_p, nt=nt, mt=mt, interpret=interpret
+            qp, S, acc_2d, norms_2d, nt=nt, mt=mt, interpret=interpret
         )
-        c = c[0, :M]
+        c = c[0]
 
     # Final reduction over the per-block maxima (tiny: M/mt entries, one
     # per lane row; see kernel._write_block_max).
@@ -82,5 +78,5 @@ def greedy_update(
     blk = jnp.argmax(bmax)
     max_res = bmax[blk]
     argmax = bidx[blk]
-    acc_out = acc_out[0, :M].astype(acc.dtype)
+    acc_out = acc_out[0].astype(acc.dtype)
     return c, acc_out, max_res.astype(norms_sq.dtype), argmax

@@ -52,6 +52,45 @@ def test_greedy_update_sweep(rng, dtype, shape):
     assert int(am) == int(amr)
 
 
+# S is swept at its own shape: the interpreter fills the part of a partial
+# block beyond the array with NaN, so a missing row or column mask shows
+# as NaN or a wrong argmax.  N = 256 j + 16 is 10,000's remainder at the
+# default row tile.
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64])
+@pytest.mark.parametrize("N,M,tiles,last_wins", [
+    (272, 1029, {}, False),                      # one full row tile + 16
+    (528, 1029, {}, True),                       # two + 16; argmax = M - 1
+    (400, 385, {"nt": 128, "mt": 128}, False),   # M = 3 mt + 1
+    (400, 385, {"nt": 128, "mt": 128}, True),
+    (272, 2048, {"mt": 1024}, True),             # ragged rows only
+], ids=["n272-m1029", "n528-m1029-last", "n400-m385", "n400-m385-last",
+        "n272-m2048-last"])
+def test_greedy_update_ragged_tiles(rng, dtype, N, M, tiles, last_wins):
+    S = _mk(rng, (N, M), dtype)
+    q = _mk(rng, (N,), dtype)
+    q = q / np.linalg.norm(q)
+    acc = np.abs(rng.standard_normal(M)).astype(np.float32)
+    norms = np.sum(np.abs(S) ** 2, axis=0).astype(np.float32)
+    if last_wins:
+        norms[-1] += 1e4
+    args = [jnp.asarray(a) for a in (q, S, acc, norms)]
+
+    c, a, mx, am = greedy_update(*args, **tiles)
+    cr, ar, mxr, amr = greedy_update_ref(*args)
+    assert c.shape == (M,) and a.shape == (M,)
+    assert np.all(np.isfinite(np.asarray(c)))
+    assert np.all(np.isfinite(np.asarray(a)))
+    scale = float(jnp.max(jnp.abs(cr)))
+    np.testing.assert_allclose(np.asarray(c), np.asarray(cr),
+                               rtol=1e-4, atol=1e-4 * scale)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(ar),
+                               rtol=1e-4, atol=1e-3 * scale ** 2)
+    assert float(mx) == pytest.approx(float(mxr), rel=1e-5)
+    assert int(am) == int(amr)
+    if last_wins:
+        assert int(am) == M - 1
+
+
 @settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 9999), n=st.integers(8, 200),
        m=st.integers(8, 300),
