@@ -537,6 +537,18 @@ def make_dist_refresh(mesh: Mesh):
     return jax.jit(sharded, donate_argnums=(1,))
 
 
+def _place_columns(S, mesh: Mesh):
+    """``S`` (anything :func:`repro.data.providers.as_provider` accepts)
+    as an array column-sharded over every mesh axis."""
+    from repro.data.providers import materialize_source
+
+    S = materialize_source(S)
+    s_sharding = NamedSharding(mesh, P(None, tuple(mesh.axis_names)))
+    if getattr(S, "sharding", None) != s_sharding:
+        S = jax.device_put(S, s_sharding)
+    return S
+
+
 @traced("repro.driver")
 def distributed_greedy(
     S,
@@ -588,12 +600,6 @@ def distributed_greedy(
     ``S`` may be anything :func:`repro.data.providers.as_provider`
     accepts; non-array sources are materialized before placement.
     """
-    from repro.data.providers import materialize_source
-
-    S = materialize_source(S)
-    s_sharding = NamedSharding(mesh, P(None, tuple(mesh.axis_names)))
-    if getattr(S, "sharding", None) != s_sharding:
-        S = jax.device_put(S, s_sharding)
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
     if block_p < 1:
@@ -606,37 +612,41 @@ def distributed_greedy(
             panel=panel_ortho, checkpoint_dir=checkpoint_dir, resume=resume,
         )
 
-    chunk_fn = make_dist_greedy_chunk(
-        mesh, chunk, kappa, max_passes, backend,
-        check_refresh=(refresh == "auto"),
-        donate=(callback is None),
-    )
-    refresh_fn = make_dist_refresh(mesh)
-    state = dist_greedy_init(S, max_k, mesh)
+    with span("repro.driver.init"):
+        S = _place_columns(S, mesh)
+        chunk_fn = make_dist_greedy_chunk(
+            mesh, chunk, kappa, max_passes, backend,
+            check_refresh=(refresh == "auto"),
+            donate=(callback is None),
+        )
+        refresh_fn = make_dist_refresh(mesh)
+        state = dist_greedy_init(S, max_k, mesh)
 
-    rdt = state.norms_sq.dtype
-    eps = float(jnp.finfo(rdt).eps)
-    ref_sq = float(jnp.max(state.norms_sq))
-    scale = ref_sq ** 0.5
-    done = False
-    final_stop = STOP_NONE
-    seq = 0
-    if checkpoint_dir is not None:
-        from repro.checkpoint.io import latest_step
+        rdt = state.norms_sq.dtype
+        eps = float(jnp.finfo(rdt).eps)
+        ref_sq = float(jnp.max(state.norms_sq))
+        scale = ref_sq ** 0.5
+        done = False
+        final_stop = STOP_NONE
+        seq = 0
+        if checkpoint_dir is not None:
+            from repro.checkpoint.io import latest_step
 
-        tree = load_resident_checkpoint(checkpoint_dir) if resume else None
-        if tree is not None:
-            _validate_resident_tree(tree, S.shape[0], S.shape[1], max_k,
-                                    S.dtype, "resume checkpoint")
-            state, ref_sq, scale, done, final_stop = \
-                _dist_state_from_tree(tree, mesh)
-        seq = latest_step(checkpoint_dir) or 0
-    # invariant thresholds device-placed once; only ref_sq changes (refresh)
-    tau_d = jnp.asarray(tau, rdt)
-    scale_d = jnp.asarray(scale, rdt)
-    safety_d = jnp.asarray(refresh_safety, rdt)
-    ref_sq_d = jnp.asarray(ref_sq, rdt)
-    k = int(state.k)
+            tree = load_resident_checkpoint(checkpoint_dir) if resume \
+                else None
+            if tree is not None:
+                _validate_resident_tree(tree, S.shape[0], S.shape[1], max_k,
+                                        S.dtype, "resume checkpoint")
+                state, ref_sq, scale, done, final_stop = \
+                    _dist_state_from_tree(tree, mesh)
+            seq = latest_step(checkpoint_dir) or 0
+        # invariant thresholds device-placed once; only ref_sq changes
+        # (refresh)
+        tau_d = jnp.asarray(tau, rdt)
+        scale_d = jnp.asarray(scale, rdt)
+        safety_d = jnp.asarray(refresh_safety, rdt)
+        ref_sq_d = jnp.asarray(ref_sq, rdt)
+        k = int(state.k)
     while not done and k < max_k:
         with span("repro.driver.chunk", k=k):
             state, n_done, stop = chunk_fn(
@@ -659,9 +669,10 @@ def distributed_greedy(
                 state = state._replace(k=jnp.asarray(k, jnp.int32))
                 done, final_stop = True, STOP_RANK
             elif stop == STOP_REFRESH:
-                state = refresh_fn(S, state)
-                ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
-                ref_sq_d = jnp.asarray(ref_sq, rdt)
+                with span("repro.driver.refresh"):
+                    state = refresh_fn(S, state)
+                    ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
+                    ref_sq_d = jnp.asarray(ref_sq, rdt)
                 if ref_sq ** 0.5 < tau:
                     done, final_stop = True, STOP_TAU
                 elif ref_sq ** 0.5 <= floor_estimate(eps, scale, k):
@@ -703,47 +714,50 @@ def _distributed_block_greedy(
     ``block_p > 1``).  ``chunk`` counts BLOCKS per host round-trip;
     ``callback(state)`` fires once per chunk (non-donating, as in the
     stepwise driver)."""
-    N, M = S.shape
-    n_dev = int(mesh.devices.size)
-    m_loc = M // n_dev
-    p = min(p, min(N, M))
-    if p > m_loc:
-        raise ValueError(
-            f"block_p={p} exceeds the per-device column count {m_loc} "
-            f"(M={M} over {n_dev} devices) — the local top-p selection "
-            f"needs p candidates per shard"
+    with span("repro.driver.init"):
+        S = _place_columns(S, mesh)
+        N, M = S.shape
+        n_dev = int(mesh.devices.size)
+        m_loc = M // n_dev
+        p = min(p, min(N, M))
+        if p > m_loc:
+            raise ValueError(
+                f"block_p={p} exceeds the per-device column count {m_loc} "
+                f"(M={M} over {n_dev} devices) — the local top-p selection "
+                f"needs p candidates per shard"
+            )
+        max_k = min(max_k, N, M)  # the accepted-basis cap
+        max_slots = min(max_k + p, min(N, M) + p)  # + hole headroom
+        chunk_fn = make_dist_block_greedy_chunk(
+            mesh, chunk, p, kappa, max_passes, backend,
+            check_refresh=(refresh == "auto"), donate=(callback is None),
+            panel=panel,
         )
-    max_k = min(max_k, N, M)  # the accepted-basis cap
-    max_slots = min(max_k + p, min(N, M) + p)  # + hole headroom
-    chunk_fn = make_dist_block_greedy_chunk(
-        mesh, chunk, p, kappa, max_passes, backend,
-        check_refresh=(refresh == "auto"), donate=(callback is None),
-        panel=panel,
-    )
-    refresh_fn = make_dist_refresh(mesh)
-    state = dist_greedy_init(S, max_slots, mesh)
+        refresh_fn = make_dist_refresh(mesh)
+        state = dist_greedy_init(S, max_slots, mesh)
 
-    rdt = state.norms_sq.dtype
-    eps = float(jnp.finfo(rdt).eps)
-    ref_sq = float(jnp.max(state.norms_sq))
-    scale = ref_sq ** 0.5  # fixed global column scale for the rank guard
-    done = False
-    final_stop = STOP_NONE
-    seq = 0
-    if checkpoint_dir is not None:
-        from repro.checkpoint.io import latest_step
+        rdt = state.norms_sq.dtype
+        eps = float(jnp.finfo(rdt).eps)
+        ref_sq = float(jnp.max(state.norms_sq))
+        scale = ref_sq ** 0.5  # fixed global column scale for the rank guard
+        done = False
+        final_stop = STOP_NONE
+        seq = 0
+        if checkpoint_dir is not None:
+            from repro.checkpoint.io import latest_step
 
-        tree = load_resident_checkpoint(checkpoint_dir) if resume else None
-        if tree is not None:
-            _validate_resident_tree(tree, N, M, max_slots, S.dtype,
-                                    "resume checkpoint")
-            state, ref_sq, scale, done, final_stop = \
-                _dist_state_from_tree(tree, mesh)
-        seq = latest_step(checkpoint_dir) or 0
-    tau_d = jnp.asarray(tau, rdt)
-    scale_d = jnp.asarray(scale, rdt)
-    safety_d = jnp.asarray(refresh_safety, rdt)
-    ref_sq_d = jnp.asarray(ref_sq, rdt)
+            tree = load_resident_checkpoint(checkpoint_dir) if resume \
+                else None
+            if tree is not None:
+                _validate_resident_tree(tree, N, M, max_slots, S.dtype,
+                                        "resume checkpoint")
+                state, ref_sq, scale, done, final_stop = \
+                    _dist_state_from_tree(tree, mesh)
+            seq = latest_step(checkpoint_dir) or 0
+        tau_d = jnp.asarray(tau, rdt)
+        scale_d = jnp.asarray(scale, rdt)
+        safety_d = jnp.asarray(refresh_safety, rdt)
+        ref_sq_d = jnp.asarray(ref_sq, rdt)
     while not done and int(state.k) + p <= max_slots:
         with span("repro.driver.chunk", k=int(state.k)):
             state, n_done, stop = chunk_fn(
@@ -755,9 +769,10 @@ def _distributed_block_greedy(
             if stop == STOP_TAU or stop == STOP_RANK:
                 done, final_stop = True, stop
             elif stop == STOP_REFRESH:
-                state = refresh_fn(S, state)
-                ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
-                ref_sq_d = jnp.asarray(ref_sq, rdt)
+                with span("repro.driver.refresh"):
+                    state = refresh_fn(S, state)
+                    ref_sq = max(float(jnp.max(state.norms_sq)), 1e-300)
+                    ref_sq_d = jnp.asarray(ref_sq, rdt)
                 if ref_sq ** 0.5 < tau:
                     done, final_stop = True, STOP_TAU
                 elif ref_sq ** 0.5 <= floor_estimate(eps, scale, int(state.k)):
@@ -771,4 +786,5 @@ def _distributed_block_greedy(
     # compact holes + cap at max_k: shared with the resident blocked driver
     from repro.core.block_greedy import _compact_result
 
-    return _compact_result(state, max_k, final_stop)
+    with span("repro.driver.compact"):
+        return _compact_result(state, max_k, final_stop)

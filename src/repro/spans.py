@@ -25,6 +25,15 @@ SPANS = (
     ("repro.driver.chunk", "one pass of a driver's chunk loop: dispatch, "
                            "the host syncs of k and the stop code, stop "
                            "handling and refresh; metadata: k"),
+    ("repro.driver.init", "a distributed driver's set-up: S placed on "
+                          "the mesh, the state made, the first host sync "
+                          "(the largest column norm), the thresholds "
+                          "placed"),
+    ("repro.driver.refresh", "a distributed driver's exact recomputation "
+                             "of the residuals and its host sync"),
+    ("repro.driver.compact", "a distributed blocked build's hole columns "
+                             "dropped (block_greedy._compact_result): eager, "
+                             "syncing on the data-dependent count"),
     ("repro.build.to_host", "copy of the pivots, errs and R to the host "
                             "after a greedy build"),
     # serving path

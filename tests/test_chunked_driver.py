@@ -134,29 +134,53 @@ import jax
 jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp, numpy as np, json
 from jax.sharding import Mesh
-from repro.core import rb_greedy_stepwise
+from repro.core import distributed, rb_greedy_stepwise
 from repro.core.distributed import distributed_greedy
 
 x = np.linspace(0, 1, 200)
 nu = np.linspace(0.5, 2.0, 120)
 S = np.stack([np.sin(2*np.pi*v*x)*np.exp(-v*x) for v in nu], axis=1)
-S = jnp.asarray(S * np.exp(1j*np.outer(x, nu)))
+S = S * np.exp(1j*np.outer(x, nu))
 
-ser = rb_greedy_stepwise(S, tau=1e-5)
+ser = rb_greedy_stepwise(jnp.asarray(S), tau=1e-5)
 k = int(ser.k)
+# exact residual^2 of every column after j serial bases, in NumPy
+C = np.asarray(ser.Q[:, :k]).conj().T @ S
+norms = np.sum(np.abs(S) ** 2, axis=0)
+res2 = norms - np.concatenate([np.zeros((1, S.shape[1])),
+                               np.cumsum(np.abs(C) ** 2, axis=0)[:-1]])
 mesh = Mesh(np.asarray(jax.devices()), ("cols",))
-out = {"n_devices": len(jax.devices())}
-for chunk in (1, 8):
-    d = distributed_greedy(S, tau=1e-5, max_k=min(*S.shape), mesh=mesh,
-                           chunk=chunk)
+
+
+def run(chunk):
+    d = distributed_greedy(jnp.asarray(S), tau=1e-5, max_k=min(*S.shape),
+                           mesh=mesh, chunk=chunk)
     kd = int(d.k)
-    out[f"chunk{chunk}"] = {
-        "k_serial": k, "k_dist": kd,
-        "pivots_equal": bool(np.array_equal(np.asarray(ser.pivots[:k]),
-                                            np.asarray(d.pivots[:kd]))),
-        "max_err_diff": float(np.max(np.abs(
-            np.asarray(ser.errs[:k]) - np.asarray(d.errs[:kd])))),
-    }
+    return {"pivots": np.asarray(d.pivots[:kd]).tolist(),
+            "errs": np.asarray(d.errs[:kd]).tolist()}
+
+
+class Patched:
+    def __init__(self, module, **names):
+        self._module, self._names = module, names
+
+    def __getattr__(self, name):
+        return self._names.get(name, getattr(self._module, name))
+
+
+out = {"n_devices": len(jax.devices()),
+       "serial": {"pivots": np.asarray(ser.pivots[:k]).tolist(),
+                  "errs": np.asarray(ser.errs[:k]).tolist()},
+       "res2": res2.tolist(), "scale2": float(norms.max()),
+       "eps": float(np.finfo(np.float64).eps)}
+for chunk in (1, 8):
+    out[f"chunk{chunk}"] = run(chunk)
+# a planted fault: every argmax of the driver takes the runner-up
+distributed.jnp = Patched(jnp, argmax=lambda x, *a, **kw:
+                          jax.lax.top_k(x, 2)[1][1])
+distributed._make_dist_greedy_chunk.cache_clear()
+jax.clear_caches()
+out["wrong_pivot"] = run(8)
 print("RESULT " + json.dumps(out))
 """
 
@@ -176,9 +200,44 @@ def dist_chunk_result():
     return json.loads(line[len("RESULT "):])
 
 
+# err_j^2 is |s|^2 - acc (Eq. 6.3): a difference of two numbers of the
+# size of |s|^2_max, so any two summation orders of the same sweep leave
+# it a few eps * |s|^2_max apart whatever err_j is, and err_j itself
+# eps * |s|^2_max / err_j apart (2.5e-10 at err 5.6e-5 here: the serial
+# and the sharded reductions sum in different orders).  Pivots are the
+# argmax of those residuals, so they may swap where two residuals^2 lie
+# within that much of each other, and the two builds then part ways.
+ERR2_TOL_EPS = 100.0
+
+
+def _departures(r, dist):
+    """Where ``dist`` departs from the serial build beyond what floating
+    point allows: a different pivot that is not a near-tie, or an err^2
+    off by more than the tolerance.  Empty when they agree."""
+    ser, res2 = r["serial"], np.asarray(r["res2"])
+    tol2 = ERR2_TOL_EPS * r["eps"] * r["scale2"]
+    out = []
+    if len(dist["pivots"]) != len(ser["pivots"]):
+        out.append(("k", len(ser["pivots"]), len(dist["pivots"])))
+    for j, (a, b) in enumerate(zip(dist["pivots"], ser["pivots"])):
+        if a != b:
+            gap = abs(res2[j, a] - res2[j, b])
+            if gap > tol2:
+                out.append(("pivot", j, a, b, gap))
+            break   # past a near-tie the two builds differ by design
+        gap2 = abs(dist["errs"][j] ** 2 - ser["errs"][j] ** 2)
+        if gap2 > tol2:
+            out.append(("err2", j, gap2, tol2))
+    return out
+
+
 @pytest.mark.parametrize("chunk", [1, 8])
 def test_distributed_chunked_matches_serial(dist_chunk_result, chunk):
-    r = dist_chunk_result[f"chunk{chunk}"]
-    assert r["k_dist"] == r["k_serial"]
-    assert r["pivots_equal"]
-    assert r["max_err_diff"] < 1e-10
+    assert dist_chunk_result["n_devices"] == 4
+    assert _departures(dist_chunk_result,
+                       dist_chunk_result[f"chunk{chunk}"]) == []
+
+
+def test_distributed_parity_rejects_a_wrong_pivot(dist_chunk_result):
+    bad = _departures(dist_chunk_result, dist_chunk_result["wrong_pivot"])
+    assert bad and bad[0][0] == "pivot"

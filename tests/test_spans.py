@@ -4,8 +4,11 @@ small size, and the spans on the profile's host plane are checked for
 their nesting, their counts and their names."""
 
 import glob
+import json
 import math
 import os
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -76,6 +79,62 @@ def build_profiles(tmp_path_factory):
     return out
 
 
+# A stepwise and a blocked distributed build on four CPU devices, run in a
+# child (the device count is fixed when JAX starts), under the profiler.
+# The family's residuals fall below the refresh trigger within max_k, so
+# both drivers refresh, and the blocked one compacts its hole columns.
+_DIST_SCRIPT = r"""
+import glob, json, os, sys
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro.api import build_basis
+
+x = np.linspace(0, 1, 64)
+nu = np.linspace(0.5, 2.0, 128)
+S = (np.stack([np.sin(2 * np.pi * v * x) * np.exp(-v * x) for v in nu], 1)
+     * np.exp(1j * np.outer(x, nu))).astype(np.complex64)
+mesh = Mesh(np.asarray(jax.devices()), ("cols",))
+
+
+def run():
+    return [build_basis(source=S, strategy="distributed", mesh=mesh,
+                        block_p=p, max_k=32, chunk=4, tau=1e-12,
+                        backend="xla") for p in (1, 2)]
+
+
+run()   # compile
+opts = jax.profiler.ProfileOptions()
+opts.python_tracer_level = 0
+with jax.profiler.trace(sys.argv[1], profiler_options=opts):
+    run()
+(path,) = glob.glob(os.path.join(sys.argv[1], "**", "*.xplane.pb"),
+                    recursive=True)
+spans = []
+for plane in jax.profiler.ProfileData.from_file(path).planes:
+    if plane.name == "/host:CPU":
+        for i, line in enumerate(plane.lines):
+            spans += [(e.name, e.start_ns, e.start_ns + e.duration_ns, i)
+                      for e in line.events if e.name.startswith("repro.")]
+print("SPANS " + json.dumps(sorted(spans, key=lambda s: s[1])))
+"""
+
+
+@pytest.fixture(scope="module")
+def dist_profile(tmp_path_factory):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    proc = subprocess.run(
+        [sys.executable, "-c", _DIST_SCRIPT,
+         str(tmp_path_factory.mktemp("dist"))],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("SPANS ")][-1]
+    return [tuple(s) for s in json.loads(line[len("SPANS "):])]
+
+
 @pytest.fixture(scope="module")
 def serve_profile(tmp_path_factory):
     basis = build_basis(source=_matrix(1), strategy="greedy", max_k=K,
@@ -107,6 +166,23 @@ def test_build_spans_nest(build_profiles, strategy):
     (to_host,) = _within(spans, "repro.build.to_host", build)
     # the copy to the host follows the driver, outside it
     assert to_host[1] >= driver[2]
+
+
+@pytest.mark.parametrize("blocked", [False, True])
+def test_distributed_driver_names_its_init_refresh_and_compaction(
+        dist_profile, blocked):
+    builds = _named(dist_profile, "repro.build")
+    assert len(builds) == 2
+    (driver,) = _within(dist_profile, "repro.driver", builds[blocked])
+    (init,) = _within(dist_profile, "repro.driver.init", driver)
+    chunks = _within(dist_profile, "repro.driver.chunk", driver)
+    assert chunks and init[2] <= chunks[0][1]
+    (refresh,) = _within(dist_profile, "repro.driver.refresh", driver)
+    assert any(_inside(refresh, c) for c in chunks)
+    compact = _within(dist_profile, "repro.driver.compact", driver)
+    assert len(compact) == blocked
+    if blocked:
+        assert compact[0][1] >= chunks[-1][2]
 
 
 def test_stepwise_build_has_one_chunk_span_per_chunk(build_profiles):
@@ -141,8 +217,8 @@ def test_queue_wait_is_reported(serve_profile):
 
 
 def test_every_span_seen_is_listed_and_every_listed_span_is_seen(
-        build_profiles, serve_profile):
-    seen = {s[0] for s in serve_profile[0]}
+        build_profiles, serve_profile, dist_profile):
+    seen = {s[0] for s in serve_profile[0] + dist_profile}
     for spans, _ in build_profiles.values():
         seen |= {s[0] for s in spans}
     assert seen == NAMES
