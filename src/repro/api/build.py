@@ -31,6 +31,7 @@ drivers directly (asserted in ``tests/test_api.py``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import os
 import time
@@ -300,6 +301,34 @@ def _auto_strategy(spec: ReductionSpec, shape, dtype):
 # with nothing to checkpoint and ignore it.
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _interleaved_rows(R, k):
+    """Rows ``[:k]`` of a complex (n, M) R as (k, 2M) real data of the
+    matching width, each real part followed by its imaginary part: the
+    byte layout of the complex rows.  Merging the column axis (major)
+    with the (re, im) axis keeps a column sharding of R."""
+    R = R[:k]
+    return jnp.stack((R.real, R.imag), -1).reshape(k, 2 * R.shape[1])
+
+
+def _r_to_host(R, k: int):
+    """Rows ``[:k]`` of R as a host ndarray of R's own dtype.
+
+    A complex device R crosses as real data (:func:`_interleaved_rows`)
+    and is viewed back as complex on the host, with no host copy: the
+    TPU runtime keeps a c64 buffer as two 32-bit planes and joins them
+    on the host (``X64FromTuple``) far slower than the link moves the
+    same bytes as f32.  The values are bit-identical.  A real device R
+    is copied as it is; a host R (the streamed drivers) or None passes
+    through.
+    """
+    if R is None:
+        return None
+    if isinstance(R, np.ndarray) or not jnp.iscomplexobj(R):
+        return np.asarray(R[:k])
+    return np.asarray(_interleaved_rows(R, k)).view(R.dtype)
+
+
 def _trim_greedy(res, extras=None):
     from repro.core.greedy import STOP_NAMES
 
@@ -310,9 +339,7 @@ def _trim_greedy(res, extras=None):
         extras["stop"] = STOP_NAMES.get(int(stop), str(int(stop)))
     with span("repro.build.to_host"):
         return (res.Q[:, :k], np.asarray(res.pivots[:k]),
-                np.asarray(res.errs[:k]),
-                None if res.R is None else np.asarray(res.R[:k]), k,
-                extras)
+                np.asarray(res.errs[:k]), _r_to_host(res.R, k), k, extras)
 
 
 def _build_greedy(spec, S, ckpt_dir=None):

@@ -35,7 +35,8 @@ SPANS = (
                              "dropped (block_greedy._compact_result): eager, "
                              "syncing on the data-dependent count"),
     ("repro.build.to_host", "copy of the pivots, errs and R to the host "
-                            "after a greedy build"),
+                            "after a greedy build; a complex R crosses "
+                            "as real data, f32 for c64"),
     # serving path
     ("repro.serve.submit", "ROQEngine.submit: breakers, admission, "
                            "enqueue; metadata: req (an ordinal)"),
